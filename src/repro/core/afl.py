@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -570,10 +571,11 @@ def _run_compiled(params0, fleet, plane, *, algorithm, iterations, tau_u,
     ``autosave_fn`` hook (DESIGN.md §10)."""
     from repro.core import event_trace as _et
 
-    trace = _et.compile_afl_trace(
-        fleet, algorithm=algorithm, iterations=iterations, tau_u=tau_u,
-        tau_d=tau_d, gamma=gamma, mu_momentum=mu_momentum,
-        max_staleness=max_staleness, faults=faults, seed=seed)
+    with jax.profiler.TraceAnnotation("afl.compile_trace", seed=seed):
+        trace = _et.compile_afl_trace(
+            fleet, algorithm=algorithm, iterations=iterations, tau_u=tau_u,
+            tau_d=tau_d, gamma=gamma, mu_momentum=mu_momentum,
+            max_staleness=max_staleness, faults=faults, seed=seed)
     runner = _et.CompiledLoopRunner(plane, server_opt=server_opt,
                                     server_lr=server_lr, guards=guards)
     engine = plane.engine
@@ -588,7 +590,9 @@ def _run_compiled(params0, fleet, plane, *, algorithm, iterations, tau_u,
         runner.count_launch()
         start = 0
         if eval_fn is not None:
-            hist.add(0.0, 0, eval_fn(params0))
+            with jax.profiler.TraceAnnotation("afl.eval", seed=seed,
+                                              upload=0):
+                hist.add(0.0, 0, eval_fn(params0))
     else:
         hist = history_from_state(resume_state.get("history"))
         g_flat = resume_state["g_flat"]
@@ -609,7 +613,9 @@ def _run_compiled(params0, fleet, plane, *, algorithm, iterations, tau_u,
                 f"resume cursor {start} beyond the {len(trace)}-event "
                 "trace — was the run compiled with fewer iterations?")
         if start == 0 and not hist.times and eval_fn is not None:
-            hist.add(0.0, 0, eval_fn(engine.unflatten(g_flat)))
+            with jax.profiler.TraceAnnotation("afl.eval", seed=seed,
+                                              upload=0):
+                hist.add(0.0, 0, eval_fn(engine.unflatten(g_flat)))
 
     autosave_fn = None
     if autosave_dir is not None:

@@ -270,18 +270,20 @@ class ClientPlane:
                      local_steps_override: Optional[int] = None):
         """Stage one round of batches for the WHOLE fleet: stacked
         (M, bucket, ...) leaves + the (M, bucket) step-valid mask."""
-        staged = []
-        nbs = []
-        for c in self.fleet:
-            k = local_steps_override or c.local_steps
-            b = self._staged_batches(c.cid, k, seed)
-            staged.append(b)
-            nbs.append(_num_batches(b))
-        bucket = self._bucketed(max(nbs))
-        batches = jax.tree.map(
-            lambda *xs: np.stack(xs),
-            *[_pad_batches(b, bucket) for b in staged])
-        valid = np.arange(bucket)[None, :] < np.asarray(nbs)[:, None]
+        with jax.profiler.TraceAnnotation("plane.stage_fleet",
+                                          stage_seed=seed, clients=self.M):
+            staged = []
+            nbs = []
+            for c in self.fleet:
+                k = local_steps_override or c.local_steps
+                b = self._staged_batches(c.cid, k, seed)
+                staged.append(b)
+                nbs.append(_num_batches(b))
+            bucket = self._bucketed(max(nbs))
+            batches = jax.tree.map(
+                lambda *xs: np.stack(xs),
+                *[_pad_batches(b, bucket) for b in staged])
+            valid = np.arange(bucket)[None, :] < np.asarray(nbs)[:, None]
         return batches, valid
 
     def train_all(self, g_flat: jnp.ndarray, seed: int,

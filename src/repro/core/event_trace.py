@@ -809,7 +809,18 @@ class CompiledLoopRunner:
 
     # -- staging -------------------------------------------------------------
     def _stage_events(self, trace: EventTrace, start: int):
-        return stage_trace_events(self.plane, trace, start)
+        with jax.profiler.TraceAnnotation(
+                "afl.stage_events", seed=trace.base_seed,
+                events=len(trace) - start):
+            return stage_trace_events(self.plane, trace, start)
+
+    def _segment_inputs(self, trace, staged, s0, s1, s_bucket):
+        """:func:`segment_inputs` under its span."""
+        with jax.profiler.TraceAnnotation(
+                "afl.segment_inputs", seed=trace.base_seed, s0=s0, s1=s1,
+                bucket=s_bucket):
+            return segment_inputs(trace, staged, s0, s1, s_bucket,
+                                  fedopt=self._s_update is not None)
 
     # -- execution -----------------------------------------------------------
     def _can_fold(self, trace) -> bool:
@@ -861,10 +872,9 @@ class CompiledLoopRunner:
         if self._can_fold(trace):
             return self._run_folded(trace, s0, s1, fleet_buf, g_flat,
                                     opt_state, gstate)
-        fedopt = self._s_update is not None
         if not self.paged:
-            cids, coefs, evalid, batches, svalid = segment_inputs(
-                trace, staged, s0, s1, s_bucket, fedopt=fedopt)
+            cids, coefs, evalid, batches, svalid = self._segment_inputs(
+                trace, staged, s0, s1, s_bucket)
             prog = self._prog_for(retrain, batches, opt_state)
             self.launches += 1
             self.segments += 1
@@ -882,8 +892,8 @@ class CompiledLoopRunner:
         for t0, t1 in split_for_slots(trace.cids, s0, s1, plane.P):
             ccids = np.unique(trace.cids[t0:t1])
             fleet_buf = plane.adopt_chunk(fleet_buf, ccids)
-            cids, coefs, evalid, batches, svalid = segment_inputs(
-                trace, staged, t0, t1, s_bucket, fedopt=fedopt)
+            cids, coefs, evalid, batches, svalid = self._segment_inputs(
+                trace, staged, t0, t1, s_bucket)
             slots = plane.store.slots_of(cids)
             cids = np.where(slots >= 0, slots, 0).astype(np.int32)
             prog = self._prog_for(retrain, batches, opt_state)
@@ -965,9 +975,13 @@ class CompiledLoopRunner:
                     for t0, t1 in split_for_slots(
                         trace.cids, a + s0, a + s1, self.plane.P)])
             for s0, s1, bucket in segs:
-                fleet_buf, g_flat, opt_state, gstate = self._run_segment(
-                    trace, staged, a + s0, a + s1, bucket,
-                    fleet_buf, g_flat, opt_state, gstate)
+                with jax.profiler.TraceAnnotation(
+                        "afl.segment", seed=trace.base_seed, s0=a + s0,
+                        s1=a + s1, bucket=bucket):
+                    fleet_buf, g_flat, opt_state, gstate = \
+                        self._run_segment(trace, staged, a + s0, a + s1,
+                                          bucket, fleet_buf, g_flat,
+                                          opt_state, gstate)
                 cur = a + s1
                 # mid-chunk cursors are safe save points: resume's
                 # boundary_cuts(start=cur) re-derives every boundary
@@ -985,8 +999,11 @@ class CompiledLoopRunner:
                 self.launches += 1
             if eval_fn is not None and trace.js[i] % eval_every == 0 \
                     and hist is not None:
-                hist.add(float(trace.t_complete[i]), int(trace.js[i]),
-                         eval_fn(self.engine.unflatten(g_flat)))
+                with jax.profiler.TraceAnnotation(
+                        "afl.eval", seed=trace.base_seed,
+                        upload=int(trace.js[i])):
+                    got = eval_fn(self.engine.unflatten(g_flat))
+                hist.add(float(trace.t_complete[i]), int(trace.js[i]), got)
             a = b
             # at a chunk boundary the save must come AFTER the boundary
             # actions: a cursor saved at b with the broadcast/eval still

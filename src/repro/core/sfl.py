@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import aggregation as agg
@@ -109,26 +110,28 @@ def _run_fedavg_impl(params0, fleet: Sequence[ClientSpec],
     if eval_fn is not None:
         hist.add(t, 0, eval_fn(params))
     for rnd in range(1, rounds + 1):
-        if plane is not None:
-            # whole round of local training: one vmapped scan launch
-            fleet_buf = plane.train_all(g_flat, seed * 100003 + rnd,
-                                        local_steps_override)
-            # eq. (2) straight off the fleet buffer's rows
-            g_flat = engine.weighted_sum_rows_flat(
-                0.0, g_flat, list(alpha), fleet_buf)
-        else:
-            locals_ = []
-            for c in fleet:
-                k = local_steps_override or c.local_steps
-                locals_.append(local_train_fn(params, c.cid, k,
-                                              seed * 100003 + rnd))
-            # eq. (2): w_{t+1} = Σ α_m w_t^m
-            if engine is not None:
-                g_flat, params = engine.weighted_sum_flat(
-                    0.0, g_flat, list(alpha), locals_)
+        with jax.profiler.TraceAnnotation("fedavg.round", seed=seed,
+                                          round=rnd):
+            if plane is not None:
+                # whole round of local training: one vmapped scan launch
+                fleet_buf = plane.train_all(g_flat, seed * 100003 + rnd,
+                                            local_steps_override)
+                # eq. (2) straight off the fleet buffer's rows
+                g_flat = engine.weighted_sum_rows_flat(
+                    0.0, g_flat, list(alpha), fleet_buf)
             else:
-                params = agg.weighted_sum_pytrees(
-                    0.0, params, list(alpha), locals_)
+                locals_ = []
+                for c in fleet:
+                    k = local_steps_override or c.local_steps
+                    locals_.append(local_train_fn(params, c.cid, k,
+                                                  seed * 100003 + rnd))
+                # eq. (2): w_{t+1} = Σ α_m w_t^m
+                if engine is not None:
+                    g_flat, params = engine.weighted_sum_flat(
+                        0.0, g_flat, list(alpha), locals_)
+                else:
+                    params = agg.weighted_sum_pytrees(
+                        0.0, params, list(alpha), locals_)
         t += sfl_round_time(fleet, tau_u=tau_u, tau_d=tau_d,
                             local_steps=local_steps_override or 1)
         if eval_fn is not None and rnd % eval_every == 0:
