@@ -132,16 +132,39 @@ class AggEngine:
         self.kern = kern
 
         def flatten_expr(tree):
+            """A tree with the template's structure -> one (n,) row in
+            the storage dtype."""
             ls = treedef.flatten_up_to(tree)
             return jnp.concatenate(
                 [jnp.ravel(x).astype(self.storage_dtype) for x in ls])
 
+        def flatten_rows_expr(out, trees):
+            """Trees stacked on a leading axis of C -> written leaf by
+            leaf into the (C, n) rows ``out``, in its dtype (one in-place
+            update per leaf where ``out`` is donated)."""
+            c = out.shape[0]
+            for x, off, sz in zip(treedef.flatten_up_to(trees),
+                                  self.offsets, self.sizes):
+                out = jax.lax.dynamic_update_slice_in_dim(
+                    out, x.reshape(c, sz).astype(out.dtype), off, axis=1)
+            return out
+
+        def split_expr(flat):
+            """(n,) row -> the template's tree of reshaped slices, still
+            in the ROW's dtype (the client plane's scan carry, §4)."""
+            return jax.tree.unflatten(treedef, [
+                flat[off:off + sz].reshape(sh)
+                for off, sz, sh in zip(self.offsets, self.sizes,
+                                       self.shapes)])
+
+        def cast_expr(tree):
+            """Each leaf to the template's dtype (a no-op where it is)."""
+            return jax.tree.unflatten(treedef, [
+                x.astype(dt)
+                for x, dt in zip(treedef.flatten_up_to(tree), self.dtypes)])
+
         def unflatten_expr(flat):
-            outs = []
-            for off, sz, sh, dt in zip(self.offsets, self.sizes,
-                                       self.shapes, self.dtypes):
-                outs.append(flat[off:off + sz].reshape(sh).astype(dt))
-            return jax.tree.unflatten(treedef, outs)
+            return cast_expr(split_expr(flat))
 
         def mac_xla(g_flat, client_trees, coefs):
             """Oracle MAC: stack-free FMA chain XLA fuses into one pass
@@ -237,8 +260,11 @@ class AggEngine:
             return scale * (g_flat.astype(jnp.float32)
                             - flatten_expr(client_tree).astype(jnp.float32))
 
-        self._flatten_expr = flatten_expr
         self._unflatten_expr = unflatten_expr
+        self.flatten_expr = flatten_expr
+        self.flatten_rows_expr = flatten_rows_expr
+        self.split_expr = split_expr
+        self.cast_expr = cast_expr
         self.blend_row_expr = blend_row_expr
         self.delta_row_expr = delta_row_expr
         self.blend_runs_expr = blend_runs_expr
@@ -257,9 +283,9 @@ class AggEngine:
     # -- flat store ---------------------------------------------------------
     @property
     def unflatten_expr(self):
-        """The traceable (non-jitted) unflatten expression — tasks close
-        over it to express loss/grad against the flat parameter vector
-        (``jax.grad`` through it yields a flat gradient directly)."""
+        """The traceable (non-jitted) unflatten expression,
+        ``cast_expr(split_expr(flat))`` — programs close over it to read
+        a flat row as the model's pytree (the sweep plane's evals)."""
         return self._unflatten_expr
 
     def flatten(self, tree) -> jnp.ndarray:

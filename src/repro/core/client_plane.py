@@ -12,14 +12,19 @@ This module removes all three (docs/DESIGN.md §4):
   ``dynamic_slice``s the row out (``AggEngine.blend_row_flat``), so no
   pytree is ever materialized on the event path.
 * **Scanned local SGD** — a client's K·B minibatches for one round are
-  staged as one device array up front and consumed by ``lax.scan`` over
-  the flat row: ONE dispatch per ``local_train`` call instead of one per
-  minibatch.  Tasks express the per-minibatch step against the FLAT
-  parameter vector (grad through the engine's cached unflatten
-  expression), so scan carries a single (n,) array.
+  staged as one device array up front and consumed by ``lax.scan``: ONE
+  dispatch per ``local_train`` call instead of one per minibatch.  The
+  scan carries the client model as the engine's TREE (one array per
+  leaf, ``AggEngine.split_expr``), so the flat row is split once before
+  the first step and joined once after the last: a flat carry would pay
+  a slice, a reshape and a concatenate of the whole row (on TPU, a
+  relayout) at every step.
 * **Vmapped rounds** — FedAvg rounds (and the baseline-AFL every-M
-  broadcast) ``vmap`` the scan across all M clients: a whole round of
-  fleet-wide local training is ONE launch over the (M, n) buffer.
+  broadcast) ``vmap`` the tree scan across all M clients: a whole round
+  of fleet-wide local training is ONE launch, which splits the global,
+  trains and writes each client's leaves into its row of the (M, n)
+  buffer (``AggEngine.flatten_rows_expr``).  Event windows, run-batched
+  sweeps and the sharded plane's rounds share that expression.
 * **Pow2 bucketing** — batch counts are bucketed to the next power of
   two (padded steps carry a ``valid=False`` mask and leave the row
   untouched), so a fleet whose K_m varies 1..K compiles at most
@@ -30,8 +35,14 @@ This module removes all three (docs/DESIGN.md §4):
 The plane is constructed by a task (``CNNTask.client_plane`` /
 ``LMTask.client_plane``) from two callables:
 
-``step_fn(flat_row, batch) -> flat_row``
-    one minibatch of local SGD on the (n,) flat row.  Traced inside
+``step_fn(params_tree, batch) -> params_tree``
+    one minibatch of local SGD on the engine's tree: the template's
+    structure and leaf shapes, each leaf in the ROW's dtype (the storage
+    dtype).  The step casts to the model's own dtypes inside its loss
+    (``AggEngine.cast_expr``) and returns its update in the row's dtype,
+    so every step rounds through the storage dtype exactly as the flat
+    row did (a no-op where storage and leaves are both f32).  A
+    single-leaf template's tree is the (n,) row itself.  Traced inside
     ``lax.scan`` — must be jax-pure.  A task whose step reads device
     arrays (its staged training set) passes a ``jax.tree_util.Partial``
     binding them: every plane program takes the step as its LAST
@@ -58,7 +69,7 @@ from jax.tree_util import Partial
 from repro.core.agg_engine import AggEngine, _can_donate, pow2_bucket
 from repro.core.scheduler import ClientSpec
 
-StepFn = Callable[[jnp.ndarray, Any], jnp.ndarray]
+StepFn = Callable[[Any, Any], Any]
 BatchFn = Callable[[int, int, int], Any]
 
 
@@ -146,17 +157,49 @@ class ClientPlane:
             unroll = True if jax.default_backend() == "cpu" else 1
         self.unroll = unroll
 
-        def scan_train(flat, batches, valid, lstep):
-            """Local SGD over one flat row: one program, KB fused steps."""
-            def body(w, xs):
+        # the rows are in the storage dtype, so the split tree is too and
+        # the joins' casts back to it are no-ops
+        split, join = engine.split_expr, engine.flatten_expr
+        join_rows = engine.flatten_rows_expr
+
+        def scan_tree(params, batches, valid, lstep):
+            """Local SGD over one model in the engine's tree form."""
+            def body(p, xs):
                 b, v = xs
-                w2 = lstep(w, b).astype(w.dtype)
-                return jnp.where(v, w2, w), None
-            out, _ = jax.lax.scan(body, flat, (batches, valid),
+                p2 = lstep(p, b)
+                return jax.tree.map(
+                    lambda a, o: jnp.where(v, a.astype(o.dtype), o),
+                    p2, p), None
+            out, _ = jax.lax.scan(body, params, (batches, valid),
                                   unroll=unroll)
             return out
 
+        def scan_train(flat, batches, valid, lstep):
+            """Local SGD over one flat row: one program, KB fused steps,
+            the model carried per leaf between the first and last."""
+            return join(scan_tree(split(flat), batches, valid, lstep))
+
+        def fleet_train(out, gs, batches, valid, lstep, g_axis=None):
+            """C clients' local SGD inside one program: the shared global
+            (``g_axis`` None) or the C globals (``g_axis`` 0) split, the
+            tree scan vmapped over the clients, and every client's model
+            written leaf by leaf into the (C, n) rows ``out``.  Joined
+            by a concatenate instead, the TPU compiler kept the paper
+            CNN's conv1 weight carry in on-chip memory and tiled its
+            gradient smaller, summing it in another order than a plain
+            vmapped per-leaf round (``chipbench/reference.py``): the
+            rows then drift from it by rounding (M = 100, v5e)."""
+            params = split(gs) if g_axis is None else jax.vmap(split)(gs)
+            trees = jax.vmap(scan_tree, in_axes=(g_axis, 0, 0, None))(
+                params, batches, valid, lstep)
+            return join_rows(out, trees)
+
+        def new_rows(c):
+            return jnp.zeros((c, engine.n), engine.storage_dtype)
+
         self._scan_train = scan_train          # subclasses re-map this
+        self._fleet_train = fleet_train
+        self._new_rows = new_rows
         self._train_flat = jax.jit(scan_train)
 
         def train_row(fleet_buf, g_flat, cid, batches, valid, lstep):
@@ -166,14 +209,17 @@ class ClientPlane:
 
         self._train_row = jax.jit(
             train_row, donate_argnums=(0,) if donate else ())
+        # the fleet round writes into a fresh (M, n) buffer it is handed
+        # (donated): the rows' join then updates it in place.  A lambda:
+        # the benchmark's fleet_round_ms reads its program, jit__lambda
         self._train_all = jax.jit(
-            lambda g_flat, batches, valid, lstep: jax.vmap(
-                scan_train, in_axes=(None, 0, 0, None))(
-                    g_flat, batches, valid, lstep))
+            lambda out, g_flat, batches, valid, lstep: fleet_train(
+                out, g_flat, batches, valid, lstep),
+            donate_argnums=(0,) if donate else ())
 
         def train_rows(fleet_buf, gs, cids, batches, valid, lstep):
-            rows = jax.vmap(scan_train, in_axes=(0, 0, 0, None))(
-                gs, batches, valid, lstep)                      # (W, n)
+            rows = fleet_train(new_rows(cids.shape[0]), gs, batches,
+                               valid, lstep, g_axis=0)          # (W, n)
             return fleet_buf.at[cids].set(rows)
 
         self._train_rows = jax.jit(
@@ -182,15 +228,16 @@ class ClientPlane:
         # RUN-BATCHED fleet round (the sweep plane's init/broadcast path,
         # docs/DESIGN.md §8): R independent runs' fleet-wide rounds as ONE
         # launch over (R, n) globals and (R, M, S, ...) staged batches —
-        # ``lax.map`` over the run axis of the vmapped per-client scan
-        # (jit's own cache keys the batch-tree structure/shape variants).
+        # ``lax.map`` over the run axis of the fleet round (jit's own
+        # cache keys the batch-tree structure/shape variants).
         # A vmap over runs of the vmap over clients compiles the paper
         # CNN's R=4, M=100 round for a TPU v5e in 11 minutes; the mapped
         # fleet round compiles once, like ``train_all``.
         def train_all_runs_body(g_flats, batches, valid, lstep):
-            per_run = jax.vmap(scan_train, in_axes=(None, 0, 0, None))
-            return jax.lax.map(lambda a: per_run(*a, lstep),
-                               (g_flats, batches, valid))
+            c = valid.shape[1]
+            return jax.lax.map(
+                lambda a: fleet_train(new_rows(c), *a, lstep),
+                (g_flats, batches, valid))
 
         self._train_all_runs = jax.jit(train_all_runs_body)
 
@@ -289,9 +336,15 @@ class ClientPlane:
     def train_all(self, g_flat: jnp.ndarray, seed: int,
                   local_steps_override: Optional[int] = None) -> jnp.ndarray:
         """One fleet-wide round (FedAvg round / baseline-AFL broadcast):
-        vmap the scanned local SGD across all M rows — ONE launch."""
+        vmap the scanned local SGD across all M rows — ONE launch, into
+        a fresh (M, n) buffer."""
         batches, valid = self._stage_fleet(seed, local_steps_override)
-        return self._train_all(g_flat, batches, valid, self._lstep)
+        return self._fleet_rows(g_flat, batches, valid)
+
+    def _fleet_rows(self, g_flat, batches, valid) -> jnp.ndarray:
+        """Every staged client's local SGD from ``g_flat``: (C, n) rows."""
+        return self._train_all(self._new_rows(valid.shape[0]), g_flat,
+                               batches, valid, self._lstep)
 
     def train_all_runs(self, g_flats: jnp.ndarray, batches,
                        valid) -> jnp.ndarray:
@@ -438,11 +491,11 @@ class ShardedClientPlane(ClientPlane):
         return total
 
     def _sharded_train_all(self, batches):
-        P, ax, scan_train = self._P, self._ax, self._scan_train
+        P, ax = self._P, self._ax
+        fleet_train, new_rows = self._fleet_train, self._new_rows
 
         def body(g, b, v, lstep):
-            return jax.vmap(scan_train, in_axes=(None, 0, 0, None))(
-                g, b, v, lstep)
+            return fleet_train(new_rows(v.shape[0]), g, b, v, lstep)
 
         specs = (P(), self._sspec.fleet_batch_specs(batches),
                  P(ax, None), P())
@@ -473,11 +526,12 @@ class ShardedClientPlane(ClientPlane):
         return jax.jit(f, donate_argnums=(0,) if self.donate else ())
 
     def _sharded_train_rows(self, batches):
-        P, ax, scan_train = self._P, self._ax, self._scan_train
+        P, ax = self._P, self._ax
+        fleet_train, new_rows = self._fleet_train, self._new_rows
 
         def body(buf, gs, lcids, wvalid, b, v, lstep):
-            rows = jax.vmap(scan_train, in_axes=(0, 0, 0, None))(
-                gs, b, v, lstep)                           # (W_b, n)
+            rows = fleet_train(new_rows(v.shape[0]), gs, b, v, lstep,
+                               g_axis=0)                   # (W_b, n)
             cur = buf[lcids]
             out = jnp.where(wvalid[:, None], rows.astype(buf.dtype), cur)
             # duplicate lcids (pads) always scatter identical values, so
@@ -686,7 +740,7 @@ class PagedClientPlane(ClientPlane):
         batches = jax.tree.map(lambda *xs: np.stack(xs), *trees)
         valid = np.arange(bucket)[None, :] < \
             np.asarray(nbs + nbs[:1] * (kb - k))[:, None]
-        rows = self._train_all(g_dev, batches, valid, self._lstep)
+        rows = self._fleet_rows(g_dev, batches, valid)
         return np.asarray(rows[:k])
 
     # -- fused local training (slot-addressed) -------------------------------
@@ -719,8 +773,9 @@ class PagedClientPlane(ClientPlane):
     def seed_store_from_staged(self, g_flat, staged_fleet) -> None:
         """Arena-resident fleet round from a pre-staged ``_stage_fleet``
         batch stack (the sweep plane's init/broadcast path — the staging
-        and its fleet-wide bucket are shared with the dense twin, so the
-        rows match ``train_all_runs`` bit-for-bit)."""
+        and its fleet-wide bucket are shared with the dense twin, and
+        both run the plane's one fleet-round expression, so the rows are
+        ``train_all_runs``'s up to the compiler's order of sums)."""
         batches, valid = staged_fleet
         for a in range(0, self.M, self.P):
             hi = min(a + self.P, self.M)
@@ -733,7 +788,7 @@ class PagedClientPlane(ClientPlane):
                     lambda x: np.concatenate(
                         [x, np.repeat(x[:1], kb - k, axis=0)]), b)
                 v = np.concatenate([v, np.repeat(v[:1], kb - k, axis=0)])
-            rows = self._train_all(g_flat, b, v, self._lstep)
+            rows = self._fleet_rows(g_flat, b, v)
             self.store.write_rows(np.arange(a, hi), np.asarray(rows)[:k])
             self.store.note_transient(k)
         self.store.cancel_plan()

@@ -13,9 +13,9 @@ Both tasks expose two local-training surfaces:
   by index, so even the reference path never re-uploads image tensors
   host→device inside the training loop.
 * ``client_plane(fleet)`` — the fused fleet plane (docs/DESIGN.md §4):
-  loss/grad rewritten against the engine's FLAT parameter vector via the
-  cached unflatten expression, minibatches staged per round, local SGD
-  scanned and vmapped by ``core.client_plane.ClientPlane``.
+  one SGD step on the engine's per-leaf tree in the storage dtype,
+  minibatches staged per round, local SGD scanned and vmapped by
+  ``core.client_plane.ClientPlane``.
 """
 from __future__ import annotations
 
@@ -130,8 +130,8 @@ class CNNTask:
 
     def client_plane(self, fleet, *, sharded: bool = False, clients=None,
                      **plane_kw):
-        """Fused fleet plane: grad against the flat parameter vector via
-        the engine's cached unflatten expression; batches staged as
+        """Fused fleet plane: grad against the engine's per-leaf tree
+        (the plane's scan carry, in the storage dtype); batches staged as
         index arrays (the image gather happens on device inside scan).
         ``sharded=True`` builds the fleet-mesh plane (DESIGN.md §6);
         ``clients`` overrides the shard set (scenario sweeps pass the
@@ -156,18 +156,18 @@ class CNNTask:
         template = jax.eval_shape(
             lambda: cnn_mod.init_params(self.cfg, jax.random.PRNGKey(0)))
         engine = engine_for(template)
-        unflatten = engine.unflatten_expr
+        cast = engine.cast_expr
         lr = self.lr
 
-        def step_fn(train_x, train_y, flat, batch):
+        def step_fn(train_x, train_y, tree, batch):
             if isinstance(batch, dict):      # ragged fleet: masked mean
                 idx, mask = batch["batch"], batch["sample_valid"]
             else:
                 idx, mask = batch, None
             images, labels = train_x[idx], train_y[idx]
 
-            def loss_flat(f):
-                params = unflatten(f)
+            def loss_tree(t):
+                params = cast(t)
                 if mask is None:
                     return cnn_mod.loss_fn(
                         params, {"images": images, "labels": labels})
@@ -177,8 +177,8 @@ class CNNTask:
                 m = mask.astype(jnp.float32)
                 return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
 
-            grad = jax.grad(loss_flat)(flat)
-            return flat - lr * grad
+            grads = jax.grad(loss_tree)(tree)
+            return jax.tree.map(lambda p, g: p - lr * g, tree, grads)
 
         step = Partial(step_fn, self._train_x, self._train_y)
         # advertise the {"batch", "sample_valid"} staging contract so the
@@ -293,19 +293,21 @@ class LMTask:
         template = jax.eval_shape(
             lambda: tmod.init_params(cfg, jax.random.PRNGKey(0)))
         engine = engine_for(template)
-        unflatten = engine.unflatten_expr
+        cast = engine.cast_expr
 
-        def step_fn(flat, batch):
+        def step_fn(tree, batch):
             full = dict(batch)
             full.update(self._modality_stubs(batch["tokens"].shape[0]))
 
-            def loss_flat(f):
-                loss, _ = tmod.loss_fn(unflatten(f), cfg, full)
+            def loss_tree(t):
+                loss, _ = tmod.loss_fn(cast(t), cfg, full)
                 return loss
 
-            grad = jax.grad(loss_flat)(flat)
-            return (flat.astype(jnp.float32)
-                    - lr * grad.astype(jnp.float32)).astype(flat.dtype)
+            grads = jax.grad(loss_tree)(tree)
+            return jax.tree.map(
+                lambda p, g: (p.astype(jnp.float32)
+                              - lr * g.astype(jnp.float32)).astype(p.dtype),
+                tree, grads)
 
         def batch_fn(cid, num_steps, seed):
             bs = [self.streams[cid].sample_batch(self.batch_size, seq_len)

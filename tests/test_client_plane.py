@@ -17,11 +17,12 @@ import pytest
 
 from repro.core import aggregation as agg
 from repro.core.afl import run_afl
-from repro.core.agg_engine import AggEngine
+from repro.core.agg_engine import AggEngine, engine_for
 from repro.core.client_plane import ClientPlane
 from repro.core.scheduler import AFLScheduler, ClientSpec, make_fleet
 from repro.core.sfl import run_fedavg
 from repro.core.tasks import CNNTask
+from repro.models import cnn as cnn_mod
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +279,132 @@ def test_plane_bucketing_pads_with_noop_steps():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
+# ---------------------------------------------------------------------------
+# Tree carry: the scan carries the model per leaf, the row only at its edges
+# ---------------------------------------------------------------------------
+def _flat_carry_scan(engine, train_x, train_y, lr, unroll):
+    """The oracle: local SGD with the (n,) row as the scan carry and the
+    gradient taken through the engine's unflatten expression at every
+    step — the plane's scan before it carried the tree."""
+    unflatten = engine.unflatten_expr
+
+    def step(flat, idx):
+        batch = {"images": train_x[idx], "labels": train_y[idx]}
+        grad = jax.grad(lambda f: cnn_mod.loss_fn(unflatten(f), batch))(flat)
+        return flat - lr * grad
+
+    def scan_train(flat, batches, valid):
+        def body(w, xs):
+            b, v = xs
+            return jnp.where(v, step(w, b).astype(w.dtype), w), None
+        out, _ = jax.lax.scan(body, flat, (batches, valid), unroll=unroll)
+        return out
+
+    return scan_train
+
+
+@pytest.mark.parametrize("path", ["row", "fleet"])
+@pytest.mark.parametrize("storage", [jnp.float32, jnp.bfloat16])
+def test_tree_carried_scan_matches_flat_carry(cnn_setup, storage, path):
+    """The paper CNN's retrain (one row) and fleet round (vmapped rows)
+    give bit-identical rows to the flat-carried scan, at f32 storage and
+    at bf16 storage, where every step still rounds through bf16."""
+    task, fleet, p0, cnn_plane = cnn_setup
+    eng = engine_for(jax.eval_shape(task.init_params),
+                     storage_dtype=storage)
+    plane = ClientPlane(eng, fleet, cnn_plane.step_fn, cnn_plane.batch_fn)
+    oracle = _flat_carry_scan(eng, task._train_x, task._train_y, task.lr,
+                              plane.unroll)
+    g = eng.flatten(p0)
+    if path == "row":
+        batches, valid = plane._stage_one(3, 1, seed=5)   # 3 of 4 valid
+        out = plane.local_train_flat(g, 3, 1, seed=5)
+        ref = jax.jit(oracle)(g, batches, valid)
+    else:
+        batches, valid = plane._stage_fleet(seed=5)
+        # two clients' first four steps (3 valid each): the flat carry's
+        # bf16 program is slow to compile unrolled
+        batches, valid = batches[1:3, :4], valid[1:3, :4]
+        out = plane._fleet_rows(g, batches, valid)
+        ref = jax.jit(jax.vmap(oracle, in_axes=(None, 0, 0)))(
+            g, batches, valid)
+    assert out.dtype == ref.dtype == storage
+    assert out.shape == ref.shape
+    assert not np.array_equal(np.asarray(out, np.float32),
+                              np.asarray(g, np.float32)[None])
+    assert np.asarray(out).tobytes() == np.asarray(ref).tobytes()
+
+
+def test_scan_carries_the_paper_cnn_per_leaf(cnn_setup):
+    """The rolled scan's while loop carries one array per leaf of the
+    paper CNN, never the f32[n] flat row; so does the fleet round's, one
+    leaf per client, never the (M, n) rows it writes after the loop."""
+    task, fleet, p0, cnn_plane = cnn_setup
+    plane = ClientPlane(cnn_plane.engine, fleet, cnn_plane.step_fn,
+                        cnn_plane.batch_fn, unroll=1)
+    n, M = plane.engine.n, len(fleet)
+    g = plane.engine.flatten(p0)
+    batches, valid = plane._stage_one(0, 1, seed=5)
+    text = jax.jit(plane._scan_train).lower(
+        g, batches, valid, plane._lstep).as_text()
+    loops = [ln for ln in text.splitlines() if "stablehlo.while(" in ln]
+    assert len(loops) == 1
+    carried = loops[0].split(" : ", 1)[1]
+    for shape in plane.engine.shapes:
+        assert f"tensor<{'x'.join(map(str, shape))}xf32>" in carried
+    assert f"tensor<{n}xf32>" not in carried
+    batches, valid = plane._stage_fleet(seed=5)
+    text = plane._train_all.lower(plane._new_rows(M), g, batches, valid,
+                                  plane._lstep).as_text()
+    loops = [ln for ln in text.splitlines() if "stablehlo.while(" in ln]
+    assert len(loops) == 1
+    carried = loops[0].split(" : ", 1)[1]
+    for shape in plane.engine.shapes:
+        assert f"tensor<{'x'.join(map(str, (M,) + shape))}xf32>" in carried
+    assert f"{n}xf32>" not in carried
+    assert f"tensor<{M}x{n}xf32>" in text
+
+
+@pytest.mark.parametrize("storage", [jnp.float32, jnp.bfloat16])
+def test_flatten_rows_writes_each_client_into_its_row(storage):
+    """``flatten_rows_expr`` writes a client-stacked tree into the (C, n)
+    rows it is handed: row c is ``flatten_expr`` of client c's leaves,
+    bit for bit, whatever the rows held before."""
+    tmpl = {"w": jnp.zeros((3, 4), jnp.float32),
+            "b": jnp.zeros((5,), jnp.float32)}
+    eng = AggEngine(tmpl, storage_dtype=storage)
+    rng = np.random.default_rng(0)
+    trees = {k: jnp.asarray(rng.normal(size=(6,) + v.shape), storage)
+             for k, v in tmpl.items()}
+    out = jax.jit(eng.flatten_rows_expr)(jnp.full((6, eng.n), 7.0, storage),
+                                        trees)
+    want = jax.vmap(eng.flatten_expr)(trees)
+    assert out.dtype == want.dtype == storage
+    assert np.asarray(out).tobytes() == np.asarray(want).tobytes()
+
+
+def test_split_and_flatten_round_trip_the_row():
+    """``split_expr`` and ``flatten_expr`` move a storage row between its
+    flat and tree forms bit for bit, the tree in the storage dtype;
+    ``cast_expr`` then gives the template's leaf dtypes, which is
+    ``unflatten_expr``."""
+    tmpl = {"a": jnp.zeros((3, 4), jnp.float32),
+            "b": jnp.zeros((5,), jnp.bfloat16)}
+    eng = AggEngine(tmpl, storage_dtype=jnp.bfloat16)
+    flat = jnp.asarray(np.random.default_rng(0).normal(size=eng.n),
+                       jnp.bfloat16)
+    tree = eng.split_expr(flat)
+    assert {k: (v.shape, v.dtype) for k, v in tree.items()} == \
+        {"a": ((3, 4), jnp.bfloat16), "b": ((5,), jnp.bfloat16)}
+    assert np.asarray(eng.flatten_expr(tree)).tobytes() == \
+        np.asarray(flat).tobytes()
+    params = eng.cast_expr(tree)
+    assert params["a"].dtype == jnp.float32
+    for k, v in eng.unflatten_expr(flat).items():
+        assert v.dtype == params[k].dtype
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(params[k]))
+
+
 def test_plane_train_row_updates_only_target_row(cnn_setup):
     task, fleet, p0, plane = cnn_setup
     g = plane.flatten(p0)
@@ -299,8 +426,9 @@ def test_cnn_programs_take_the_data_as_arguments(cnn_setup):
     batches, valid = plane._stage_fleet(seed=11)
     eval_flat = task.eval_flat_fn(plane.engine)
     for data, lowered in [
-            (task._train_x, plane._train_all.lower(g, batches, valid,
-                                                   plane._lstep)),
+            (task._train_x, plane._train_all.lower(
+                plane._new_rows(len(fleet)), g, batches, valid,
+                plane._lstep)),
             (task.test_x, jax.jit(lambda ev, w: ev(w)).lower(eval_flat, g))]:
         text = lowered.as_text()
         shape = "x".join(map(str, data.shape))

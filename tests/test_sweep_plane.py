@@ -299,7 +299,7 @@ def test_train_all_runs_matches_per_run_train_all():
     valid = np.stack([s[1] for s in staged])
     stacked = plane.train_all_runs(gs, batches, valid)
     for k, (r, s) in enumerate(zip(runs, staged)):
-        one = plane._train_all(gs[k], s[0], s[1], plane._lstep)
+        one = plane._fleet_rows(gs[k], s[0], s[1])
         assert _maxdiff(stacked[k], one) <= 1e-6
 
 
