@@ -89,7 +89,7 @@ def calibrate(cell, base_seed: int, seeds: int, control_seeds: int,
     ref = None
     for i, rec in enumerate(runs):
         if ref is None:
-            ref = Reference(cell.config["model"], cell.config["task"],
+            ref = Reference(cell.family, cell.config,
                             rec.answers[0].data_seed)
         t = time.perf_counter()
         got = _readings(cell, rec.answers, ref, i < control_seeds)

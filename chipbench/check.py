@@ -5,9 +5,8 @@ The reference does each step's arithmetic as the program's path does it
 (one client's local SGD as a scan, the fleet-wide round vmapped over the
 clients, eq. (3) and the weighted sum as a product per term, added in
 client order), so a sound run on the chip matches it bit for bit over a
-whole run.  The paper CNN's ReLU and max-pool decisions turn any other
-rounding into a diverging trajectory: the reference at the next lower
-precision, in the program's place, ends about 1e-3 away.  The numbers:
+whole run; how far the control lands depends on the model family
+(``families/<name>.py`` says).  The numbers:
 
 * ``order_mismatch`` (CSMAAFL) — uploads whose uploader differs from the
   reference scheduler's (a count).
@@ -24,13 +23,14 @@ precision, in the program's place, ends about 1e-3 away.  The numbers:
   program wrote back against the reference's local training from the
   program's final global, by ``gap`` from that global.
 
-``gap`` takes the worst leaf: the gap between the norm of the program's
-change (end minus start) and the reference's, over the reference's norm
-of that leaf or of the median leaf, whichever is larger; ``diff_gap``
-the norm of the difference of the two ends over the same.  Leaves whose
-reference change is under a thousandth of the median leaf's are left out
-(none of the paper CNN's are).  A model that did not move reads 1; a
-non-finite one reads inf.
+A model is compared leaf by leaf, each leaf named by its path in the
+pytree.  ``gap`` takes the worst leaf: the gap between the norm of the
+program's change (end minus start) and the reference's, over the
+reference's norm of that leaf or of the median leaf, whichever is
+larger; ``diff_gap`` the norm of the difference of the two ends over the
+same.  Leaves whose reference change is under a thousandth of the median
+leaf's are left out.  A model that did not move reads 1; a non-finite
+one reads inf.
 """
 from __future__ import annotations
 
@@ -40,7 +40,11 @@ import numpy as np
 
 
 def host(tree) -> Dict[str, np.ndarray]:
-    return {k: np.asarray(v) for k, v in tree.items()}
+    """Each leaf of a pytree as a host array, keyed by its path."""
+    import jax
+
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(p): np.asarray(v) for p, v in leaves}
 
 
 def leaf_gaps(end: Dict[str, np.ndarray], ref_end: Dict[str, np.ndarray],
@@ -89,13 +93,13 @@ def replay(ref, a, precision: str, fault: Optional[str] = None
               precision=precision, fault=fault)
     if a.kind == "afl":
         run = ref.afl(w0=a.w0, marks=tuple(a.marks), **kw, **a.run)
-        return {"params": run.params, "cids": run.cids,
-                "betas": run.betas, "marks": run.marks,
-                "head": run.marks[head(a)],
-                "row": ref.retrain(w=a.params, cid=a.last_cid,
-                                   j=a.run["iterations"], **kw)}
+        marks = {j: host(m) for j, m in run.marks.items()}
+        return {"params": host(run.params), "cids": run.cids,
+                "betas": run.betas, "marks": marks, "head": marks[head(a)],
+                "row": host(ref.retrain(w=a.params, cid=a.last_cid,
+                                        j=a.run["iterations"], **kw))}
     run = ref.fedavg(w0=a.w0, rounds=a.run["rounds"], **kw)
-    return {"params": run.params}
+    return {"params": host(run.params)}
 
 
 def head(a) -> int:

@@ -5,11 +5,13 @@ loop, FedAvg, ...).
 
 The task, the fleet and the client plane are built once in set-up and
 passed in, so every run reuses the programs the plane already compiled:
-built anew per call, each run would trace and compile again.  Every run
-starts from weights the benchmark makes from the run's seed
-(``reference.init_params``, one jitted call), and hands the task's own
-eval to the run through ``eval_fn``, under a ``bench.eval`` span; the
-eval also keeps the global it was given, for the check.
+built anew per call, each run would trace and compile again.  The task
+is the configuration's model family's (``families/<name>.py``:
+``task``).  Every run starts from weights the benchmark makes from the
+run's seed (the family's ``init_params``, one jitted call), and hands
+the task's own eval to the run through ``eval_fn``, under a
+``bench.eval`` span; the eval also keeps the global it was given, for
+the check.
 
 What a run leaves for the check (``check.py``) besides its final model:
 a CSMAAFL run the globals at its eval points and the last uploader's
@@ -49,11 +51,9 @@ class ApiRunDriver:
     def setup(self):
         import jax
 
-        from chipbench.reference import init_params
+        from chipbench.spec import family
         from repro import api
-        from repro.configs.paper_cnn import CNNConfig
         from repro.core.scheduler import make_fleet
-        from repro.core.tasks import CNNTask
 
         run = dict(self.traffic["run"])
         fleet = dict(_FLEET_DEFAULTS, **run.pop("fleet", {}))
@@ -62,16 +62,9 @@ class ApiRunDriver:
         self.cfg = api.RunConfig.from_dict(dict(run, fleet=fleet))
         self.fedavg = self.cfg.algorithm == "fedavg"
         self.iid = bool(self.traffic.get("iid", True))
-        t, m = self.task_cfg, self.model
-        cnn = CNNConfig(**{k: m[k] for k in (
-            "image_size", "channels", "conv1", "conv2", "kernel", "fc",
-            "num_classes")})
-        self.task = CNNTask(
-            variant=t["variant"], iid=self.iid,
-            num_clients=t["num_clients"], train_n=t["train_n"],
-            test_n=t["test_n"], batch_size=t["batch_size"], lr=t["lr"],
-            local_batches_per_step=t["local_batches_per_step"],
-            cnn_cfg=cnn, seed=self.data_seed)
+        fam = family(self.config.get("family"))
+        self.task = fam.task(self.config, self.iid, self.data_seed)
+        self.per_iteration = fam.samples_per_iteration(self.config)
         self.fleet = make_fleet(
             fleet["num_clients"], tau=fleet["tau"],
             hetero_a=fleet["hetero_a"],
@@ -82,7 +75,7 @@ class ApiRunDriver:
         self.plane = self.task.client_plane(self.fleet)
 
         def bench_init(s):
-            return init_params(m, s)
+            return fam.init_params(self.model, s)
 
         self.init = jax.jit(bench_init)
 
@@ -112,8 +105,7 @@ class ApiRunDriver:
         M = len(self.fleet)
         part = {"kind": "iid" if self.iid else "label",
                 "seed": self.data_seed}
-        per = self.task_cfg["local_batches_per_step"] * \
-            self.task_cfg["batch_size"]
+        per = self.per_iteration
         round_samples = sum(c.local_steps for c in self.fleet) * per
         if self.fedavg:
             params, _hist = api.run(self.task, cfg, fleet=self.fleet,

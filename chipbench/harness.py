@@ -58,8 +58,7 @@ def check_answers(cell: Cell, answers) -> Dict[str, float]:
     """The program's answers against the plain reference."""
     from chipbench.reference import Reference
 
-    ref = Reference(cell.config["model"], cell.config["task"],
-                    answers[0].data_seed)
+    ref = Reference(cell.family, cell.config, answers[0].data_seed)
     prec = cell.config["precision"]
     return check.merge(check.numbers(check.program(a),
                                      check.replay(ref, a, prec), a)

@@ -1,20 +1,19 @@
-"""Plain float32 reference of the benchmarked federated runs.
+"""Plain float32 reference of the benchmarked federated runs, shared by
+every model family.
 
 It imports nothing of the program under test (``repro``) and takes
-nothing the program made.  From a configuration, a traffic mix and a
-seed it rebuilds every input itself and replays the run the paper
-describes, one step at a time:
+nothing the program made.  From a configuration, its model family
+(``families/``), a traffic mix and a seed it rebuilds every input itself
+and replays the run the paper describes, one step at a time:
 
-* the procedural MNIST-like training set (the same generator, vectorized:
-  one draw of all the pixel noise is the same stream as one draw per
-  image);
-* the IID and label-shard partitions, the heterogeneous fleet (compute
-  time log-uniform in [tau, a*tau], adaptive K) and each client's
-  seeded minibatch order;
+* the family's training data, rebuilt from the data seed;
+* the IID and label-shard partitions of the family's per-sample keys,
+  the heterogeneous fleet (compute time log-uniform in [tau, a*tau],
+  adaptive K) and each client's seeded minibatches, drawn by the family;
 * the CSMAAFL channel scheduler (one upload at a time, the older model
   first) and the eq. (11) coefficient with its staleness EMA run as the
   plain recurrence;
-* the paper CNN (2 conv, 2 max-pool, 2 FC, log-softmax) and plain SGD;
+* local training as plain SGD on the family's loss;
 * eq. (3), w <- beta*w + (1 - beta)*w_m, one upload at a time, each
   uploader retraining from the new global; FedAvg rounds as the
   alpha-weighted sum of every client's locally trained model.
@@ -29,83 +28,28 @@ Every matmul and convolution runs at the precision it is given
 ("highest" by default: float32 on a TPU); a lower ``precision`` is the
 correctness check's control.  ``fault`` plants one of the
 faults the correctness check has to catch, for the calibration and the
-tests: ``half_batch`` trains on the first half of each minibatch and
-takes the mean over it; ``alter_answer`` hands one upload to another
-client with a beta 0.01 off (FedAvg: one client's weight to another);
-``blend_bf16`` computes every eq. (3) blend in bfloat16, and
-``blend_coef`` every one with a coefficient 0.01 off.
+tests: ``half_batch`` trains on the first half of each minibatch's batch
+axis and takes the mean over it; ``alter_answer`` hands one upload to
+another client with a beta 0.01 off (FedAvg: one client's weight to
+another); ``blend_bf16`` computes every eq. (3) blend in bfloat16, and
+``blend_coef`` every one with a coefficient 0.01 off.  None of them
+touches the family's code.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import heapq
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 FAULTS = (None, "half_batch", "alter_answer", "blend_bf16", "blend_coef")
-IMAGE_SIZE = 28
-NUM_CLASSES = 10
-# how many images the generator renders at a time (bounds host memory)
-_RENDER_CHUNK = 8192
 
 
 # ---------------------------------------------------------------------------
-# Data
+# Partitions
 # ---------------------------------------------------------------------------
-def _smooth(img: np.ndarray, iters: int) -> np.ndarray:
-    for _ in range(iters):
-        img = (img
-               + np.roll(img, 1, 0) + np.roll(img, -1, 0)
-               + np.roll(img, 1, 1) + np.roll(img, -1, 1)) / 5.0
-    return img
-
-
-def _class_template(cls: int, variant: str) -> np.ndarray:
-    rng = np.random.default_rng(1000 + cls)
-    img = rng.normal(0, 1, (IMAGE_SIZE, IMAGE_SIZE))
-    img = _smooth(img, 3 if variant == "digits" else 6)
-    q = np.quantile(img, 0.72)
-    img = np.where(img > q, 1.0, 0.0)
-    img = _smooth(img, 1)
-    return img.astype(np.float32)
-
-
-def _render(templates, labels, rng, noise) -> np.ndarray:
-    n = len(labels)
-    shifts = rng.integers(-3, 4, size=(n, 2))
-    scales = rng.uniform(0.7, 1.3, size=n)
-    out = np.empty((n, IMAGE_SIZE, IMAGE_SIZE, 1), np.float32)
-    ar = np.arange(IMAGE_SIZE)
-    for a in range(0, n, _RENDER_CHUNK):
-        b = min(n, a + _RENDER_CHUNK)
-        # np.roll by s: out[i] = in[(i - s) mod 28], per image and axis
-        r = (ar[None, :] - shifts[a:b, 0:1]) % IMAGE_SIZE
-        c = (ar[None, :] - shifts[a:b, 1:2]) % IMAGE_SIZE
-        t = templates[labels[a:b]]
-        img = t[np.arange(b - a)[:, None, None], r[:, :, None],
-                c[:, None, :]]
-        img = (img * scales[a:b, None, None]
-               + rng.normal(0, noise, (b - a, IMAGE_SIZE, IMAGE_SIZE)))
-        out[a:b, :, :, 0] = img
-    return np.clip(out, 0.0, 1.5)
-
-
-def training_set(variant: str, train_n: int, test_n: int, seed: int):
-    """(images (N, 28, 28, 1) f32, labels (N,) int32) of the training
-    split; the test labels are drawn (and dropped) to keep the stream."""
-    if variant not in ("digits", "fashion"):
-        raise ValueError(f"unknown data variant {variant!r}")
-    templates = np.stack([_class_template(c, variant)
-                          for c in range(NUM_CLASSES)])
-    noise = 0.20 if variant == "digits" else 0.30
-    rng = np.random.default_rng(seed + (0 if variant == "digits" else 77))
-    train_y = rng.integers(0, NUM_CLASSES, train_n).astype(np.int32)
-    rng.integers(0, NUM_CLASSES, test_n)
-    return _render(templates, train_y, rng, noise), train_y
-
-
 def partition(kind: str, labels: np.ndarray, num_clients: int, seed: int,
               classes_per_client: int = 2) -> List[np.ndarray]:
     """Each client's sorted sample indices: ``iid`` deals a random
@@ -124,25 +68,6 @@ def partition(kind: str, labels: np.ndarray, num_clients: int, seed: int,
                                     (c + 1) * classes_per_client]]))
             for c in range(num_clients)]
     raise ValueError(f"unknown partition {kind!r}")
-
-
-def batch_indices(indices: np.ndarray, cid: int, batch_size: int,
-                  num_batches: int, seed: int) -> np.ndarray:
-    """(num_batches, batch_size) indices into the training set: the
-    client's shard visited in a seeded random order, reshuffled when an
-    epoch runs out."""
-    rng = np.random.default_rng((seed * 9176 + cid) % (2 ** 63))
-    n = len(indices)
-    order = rng.permutation(n)
-    ptr = 0
-    rows = []
-    for _ in range(num_batches):
-        if ptr + batch_size > n:
-            order = rng.permutation(n)
-            ptr = 0
-        rows.append(order[ptr:ptr + batch_size])
-        ptr += batch_size
-    return indices[np.stack(rows)].astype(np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -214,92 +139,41 @@ def csmaafl_betas(events, *, gamma: float, mu_momentum: float):
 
 
 # ---------------------------------------------------------------------------
-# Model
+# Programs
 # ---------------------------------------------------------------------------
-def init_params(model: Dict[str, int], seed: int):
-    """He-normal paper CNN from ``jax.random.PRNGKey(seed)``; biases 0."""
-    import jax
-    import jax.numpy as jnp
-
-    k = jax.random.split(jax.random.PRNGKey(seed), 4)
-    ksz, c1, c2 = model["kernel"], model["conv1"], model["conv2"]
-    flat = (model["image_size"] // 4) ** 2 * c2
-
-    def he(kk, shape, fan_in):
-        return (jax.random.normal(kk, shape)
-                * jnp.sqrt(2.0 / fan_in)).astype(jnp.float32)
-
-    return {
-        "conv1_w": he(k[0], (ksz, ksz, model["channels"], c1),
-                      ksz * ksz * model["channels"]),
-        "conv1_b": jnp.zeros((c1,), jnp.float32),
-        "conv2_w": he(k[1], (ksz, ksz, c1, c2), ksz * ksz * c1),
-        "conv2_b": jnp.zeros((c2,), jnp.float32),
-        "fc1_w": he(k[2], (flat, model["fc"]), flat),
-        "fc1_b": jnp.zeros((model["fc"],), jnp.float32),
-        "fc2_w": he(k[3], (model["fc"], model["num_classes"]), model["fc"]),
-        "fc2_b": jnp.zeros((model["num_classes"],), jnp.float32),
-    }
-
-
-def _forward(p, x):
-    import jax
-
-    def conv(x, w, b):
-        return jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
-
-    def pool(x):
-        return jax.lax.reduce_window(x, -np.inf, jax.lax.max,
-                                     (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-
-    x = pool(jax.nn.relu(conv(x, p["conv1_w"], p["conv1_b"])))
-    x = pool(jax.nn.relu(conv(x, p["conv2_w"], p["conv2_b"])))
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ p["fc1_w"] + p["fc1_b"])
-    return jax.nn.log_softmax(x @ p["fc2_w"] + p["fc2_b"], axis=-1)
-
-
-def _loss(p, x, y):
-    import jax.numpy as jnp
-
-    logp = _forward(p, x)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
-
-
 @functools.lru_cache(maxsize=None)
-def _programs(lr: float):
-    """The reference's jitted programs.  ``upload`` is the eq. (3)
-    blend followed by local SGD from the blended model over a fixed
-    number of (index row, valid) steps, a step marked invalid leaving the
-    model as it is; with c0 = 1, c1 = 0 it is plain local training from
-    the first argument.  ``fleet`` is that local training of every
-    client from one model, vmapped over the clients, as a fleet-wide
-    round is written.  ``accumulate`` adds one client's row, picked from
-    the fleet's stacked rows, times its weight.  Every local round runs
-    with the cell's padded step count, since each compile of an SGD step
-    at "highest" precision takes minutes on a TPU host."""
+def _programs(loss: Callable, lr: float):
+    """The reference's jitted programs over a family's ``loss``.
+    ``upload`` is the eq. (3) blend followed by local SGD from the
+    blended model over a fixed number of (batch, valid) steps, a step
+    marked invalid leaving the model as it is; with c0 = 1, c1 = 0 it is
+    plain local training from the first argument.  ``fleet`` is that
+    local training of every client from one model, vmapped over the
+    clients, as a fleet-wide round is written.  ``accumulate`` adds one
+    client's row, picked from the fleet's stacked rows, times its
+    weight.  Every local round runs with the cell's padded step count,
+    since each compile of an SGD step at "highest" precision takes
+    minutes on a TPU host."""
     import jax
     import jax.numpy as jnp
 
-    def train(w, idx, valid, x, y):
+    def train(w, batches, valid, data):
         def body(w, xs):
-            ib, v = xs
-            d = jax.grad(_loss)(w, x[ib], y[ib])
+            batch, v = xs
+            d = jax.grad(loss)(w, batch, data)
             new = jax.tree.map(lambda a, b: a - lr * b, w, d)
             return jax.tree.map(lambda a, b: jnp.where(v, a, b), new, w), None
 
-        return jax.lax.scan(body, w, (idx, valid))[0]
+        return jax.lax.scan(body, w, (batches, valid))[0]
 
-    def upload(g, row, c0, c1, idx, valid, x, y):
+    def upload(g, row, c0, c1, batches, valid, data):
         g2 = jax.tree.map(lambda a, b: c0 * a + c1 * b, g, row)
-        return g2, train(g2, idx, valid, x, y)
+        return g2, train(g2, batches, valid, data)
 
     def accumulate(acc, rows, i, c):
         return jax.tree.map(lambda a, b: a + c * b[i], acc, rows)
 
-    fleet = jax.vmap(train, in_axes=(None, 0, 0, None, None))
+    fleet = jax.vmap(train, in_axes=(None, 0, 0, None))
     return jax.jit(upload), jax.jit(fleet), jax.jit(accumulate)
 
 
@@ -325,33 +199,32 @@ def _blend_bf16():
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class RefRun:
-    """What one reference run produced: final global params (host
-    numpy), and for the AFL runs the uploaders and betas in order and
-    the global after each upload j in ``marks``."""
-    params: Dict[str, np.ndarray]
+    """What one reference run produced: final global params (a pytree of
+    host arrays), and for the AFL runs the uploaders and betas in order
+    and the global after each upload j in ``marks``."""
+    params: Any
     cids: Optional[np.ndarray] = None
     betas: Optional[np.ndarray] = None
-    marks: Dict[int, Dict[str, np.ndarray]] = dataclasses.field(
-        default_factory=dict)
+    marks: Dict[int, Any] = dataclasses.field(default_factory=dict)
 
 
 class Reference:
-    """The plain replay for one configuration.  ``task`` is the
-    configuration's ``task`` block, ``model`` its ``model`` block."""
+    """The plain replay for one configuration (``config``, its whole
+    file) of model family ``family`` (a ``families/<name>.py`` module),
+    on the training data made from ``data_seed``."""
 
-    def __init__(self, model: Dict[str, int], task: Dict[str, Any],
-                 data_seed: int):
+    def __init__(self, family, config: Dict[str, Any], data_seed: int):
+        import jax
         import jax.numpy as jnp
 
-        self.model, self.task = model, task
+        self.family, self.config = family, config
+        self.task = config["task"]
         self.precision, self.fault = "highest", None
-        x, y = training_set(task["variant"], task["train_n"],
-                            task["test_n"], data_seed)
-        self.labels = y
+        data, self.keys = family.dataset(config, data_seed)
         self.data_seed = data_seed
-        self.x, self.y = jnp.asarray(x), jnp.asarray(y)
+        self.data = jax.tree.map(jnp.asarray, data)
         self._upload, self._fleet_round, self._acc = _programs(
-            float(task["lr"]))
+            family.loss, float(self.task["lr"]))
 
     def _set(self, precision: str, fault: Optional[str]) -> None:
         if fault not in FAULTS:
@@ -359,38 +232,41 @@ class Reference:
         self.precision, self.fault = precision, fault
 
     # -- pieces ---------------------------------------------------------------
-    def _idx(self, shards, cid, k, seed, steps):
-        """(steps, B) minibatch indices of one local round, padded with
-        invalid steps, and the (steps,) valid mask."""
-        idx = batch_indices(shards[cid], cid, self.task["batch_size"],
-                            k * self.task["local_batches_per_step"], seed)
+    def _batches(self, shards, cid, k, seed, steps):
+        """The family's minibatches of one local round, padded with
+        invalid steps to ``steps``, and the (steps,) valid mask."""
+        import jax
+
+        b = self.family.batches(self.config, shards[cid], cid, k, seed)
         if self.fault == "half_batch":
-            idx = idx[:, :max(1, idx.shape[1] // 2)]
-        n = idx.shape[0]
-        pad = np.zeros((steps - n, idx.shape[1]), idx.dtype)
-        return np.concatenate([idx, pad]), np.arange(steps) < n
+            b = jax.tree.map(lambda a: a[:, :max(1, a.shape[1] // 2)], b)
+        n = jax.tree.leaves(b)[0].shape[0]
+        b = jax.tree.map(lambda a: np.concatenate(
+            [a, np.zeros((steps - n,) + a.shape[1:], a.dtype)]), b)
+        return b, np.arange(steps) < n
 
     def _steps(self, ks) -> int:
         """The cell's padded local-round length: the next power of two
         of its longest round."""
-        n = max(ks) * self.task["local_batches_per_step"]
+        n = self.family.local_steps(self.config, max(ks))
         return 1 << (n - 1).bit_length()
 
     def _train(self, w, shards, cid, k, seed, steps):
         """Local SGD of client ``cid`` from ``w``."""
         one = np.float32(1.0)
-        idx, valid = self._idx(shards, cid, k, seed, steps)
-        return self._upload(w, w, one, one - one, idx, valid, self.x,
-                            self.y)[1]
+        b, valid = self._batches(shards, cid, k, seed, steps)
+        return self._upload(w, w, one, one - one, b, valid, self.data)[1]
 
     def _train_fleet(self, w, shards, ks, seed, steps):
         """Every client's local SGD from ``w``: the rows stacked on a
         leading client axis."""
-        got = [self._idx(shards, c, ks[c], seed, steps)
+        import jax
+
+        got = [self._batches(shards, c, ks[c], seed, steps)
                for c in range(len(ks))]
-        idx = np.stack([i for i, _ in got])
+        b = jax.tree.map(lambda *a: np.stack(a), *[one for one, _ in got])
         valid = np.stack([v for _, v in got])
-        return self._fleet_round(w, idx, valid, self.x, self.y)
+        return self._fleet_round(w, b, valid, self.data)
 
     def _fleet(self, fleet: Dict[str, Any]):
         return fleet_steps(
@@ -401,8 +277,10 @@ class Reference:
             base_local_steps=fleet["base_local_steps"])
 
     @staticmethod
-    def _host(tree) -> Dict[str, np.ndarray]:
-        return {k: np.asarray(v) for k, v in tree.items()}
+    def _host(tree):
+        import jax
+
+        return jax.tree.map(np.asarray, tree)
 
     # -- entries --------------------------------------------------------------
     def afl(self, *, seed: int, w0, shards, fleet: Dict[str, Any],
@@ -435,7 +313,7 @@ class Reference:
         with jax.default_matmul_precision(self.precision):
             g = w0
             fleet = self._train_fleet(w0, shards, ks, seed * 100003, steps)
-            rows = [{k: v[c] for k, v in fleet.items()}
+            rows = [jax.tree.map(lambda v: v[c], fleet)
                     for c in range(len(ks))]
             del fleet
             for (c, j, _i), beta in zip(events, betas):
@@ -443,17 +321,17 @@ class Reference:
                     beta = beta - 0.01 if beta > 0.5 else beta + 0.01
                 c0 = np.float32(beta)
                 c1 = one - c0
-                idx, valid = self._idx(shards, c, ks[c], seed * 100003 + j,
-                                       steps)
+                b, valid = self._batches(shards, c, ks[c],
+                                         seed * 100003 + j, steps)
                 if self.fault == "blend_bf16":
                     # blended apart, then trained from: 1·g + 0·g is g
                     g = _blend_bf16()(g, rows[c], c0, c1)
                     c0, c1 = one, one - one
-                    g, rows[c] = self._upload(g, g, c0, c1, idx, valid,
-                                              self.x, self.y)
+                    g, rows[c] = self._upload(g, g, c0, c1, b, valid,
+                                              self.data)
                 else:
-                    g, rows[c] = self._upload(g, rows[c], c0, c1, idx,
-                                              valid, self.x, self.y)
+                    g, rows[c] = self._upload(g, rows[c], c0, c1, b,
+                                              valid, self.data)
                 if j in marks:
                     kept[j] = self._host(g)
             params = self._host(g)
@@ -462,7 +340,7 @@ class Reference:
 
     def retrain(self, *, w, seed: int, shards, fleet: Dict[str, Any],
                 cid: int, j: int, precision: str = "highest",
-                fault: Optional[str] = None) -> Dict[str, np.ndarray]:
+                fault: Optional[str] = None):
         """Client ``cid``'s retrain after upload ``j`` of run ``seed``,
         from the global ``w``: K_cid local iterations, seed
         seed·100003 + j."""
@@ -506,5 +384,5 @@ class Reference:
         return RefRun(params)
 
     def shards(self, kind: str, seed: int, **kw) -> List[np.ndarray]:
-        return partition(kind, self.labels, self.task["num_clients"],
+        return partition(kind, self.keys, self.task["num_clients"],
                          seed, **kw)

@@ -4,7 +4,9 @@ import os
 
 import pytest
 
-from chipbench import flops
+from chipbench import flops, spec
+
+CNN = spec.family("paper_cnn")
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MNIST = {"image_size": 28, "channels": 1, "conv1": 32, "conv2": 64,
@@ -22,14 +24,14 @@ FASHION = dict(MNIST, fc=1024)
      2 * (627200 + 10035200 + 3211264 + 10240)),
 ], ids=["mnist", "fashion"])
 def test_counts_match_hand_count(model, n, fwd):
-    assert flops.param_count(model) == n
-    assert flops.forward_flops_per_sample(model) == fwd
-    assert flops.train_flops(model, 10) == 30 * fwd
+    assert CNN.param_count(model) == n
+    assert CNN.forward_flops_per_sample(model) == fwd
+    assert flops.train_flops(CNN, model, 10) == 30 * fwd
 
 
 def test_param_counts_are_the_published_sizes():
-    assert flops.param_count(MNIST) == 1663370
-    assert flops.param_count(FASHION) == 3274634
+    assert CNN.param_count(MNIST) == 1663370
+    assert CNN.param_count(FASHION) == 3274634
     with open(os.path.join(HERE, "configs", "paper-cnn-mnist.json")) as f:
         cfg = json.load(f)
     assert cfg["model"] == MNIST and cfg["param_count"] == 1663370

@@ -57,7 +57,7 @@ def _trace(program_spans):
 
 
 def _ctx(program_spans, stats):
-    cell = Cell("t", 1, {"model": MODEL, "task": {
+    cell = Cell("t", 1, {"family": "paper_cnn", "model": MODEL, "task": {
         "num_clients": 100, "batch_size": 5, "local_batches_per_step": 8}},
         {}, {}, [], [])
     return TraceContext(_trace(program_spans), cell=cell, stats=stats,

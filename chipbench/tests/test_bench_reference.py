@@ -3,15 +3,17 @@ replay agrees with the program's compiled run at a small size."""
 import numpy as np
 import pytest
 
-from chipbench import check
+from chipbench import check, spec
 from chipbench import reference as R
+
+CNN = spec.family("paper_cnn")
 
 
 @pytest.mark.parametrize("variant", ["digits", "fashion"])
 def test_training_set_matches_program_generator(variant):
     from repro.data.mnist_like import make_dataset
 
-    x, y = R.training_set(variant, 700, 90, 2 ** 31 + 5)
+    x, y = CNN.training_set(variant, 700, 90, 2 ** 31 + 5)
     ds = make_dataset(variant, train_n=700, test_n=90, seed=2 ** 31 + 5)
     assert np.array_equal(x, ds.train_x) and np.array_equal(y, ds.train_y)
 
@@ -29,7 +31,7 @@ def test_partition_and_batches_match_program(kind, kw):
     for cid in (0, 5, 11):
         want = cl[cid].indices[cl[cid].batch_indices(5, 40, 123457)]
         assert np.array_equal(
-            R.batch_indices(mine[cid], cid, 5, 40, 123457), want)
+            CNN.batch_indices(mine[cid], cid, 5, 40, 123457), want)
 
 
 def test_fleet_schedule_and_betas_match_program():
@@ -62,7 +64,7 @@ def test_init_params_match_program_init():
 
     model = {"image_size": 28, "channels": 1, "conv1": 4, "conv2": 8,
              "kernel": 5, "fc": 16, "num_classes": 10}
-    mine = R.init_params(model, 99)
+    mine = CNN.init_params(model, 99)
     theirs = cnn.init_params(CNNConfig(conv1=4, conv2=8, fc=16),
                              jax.random.PRNGKey(99))
     assert all(np.array_equal(mine[k], theirs[k]) for k in theirs)

@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from chipbench import flops, spec
+from chipbench import spec
 
 BENCH = spec.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -31,7 +31,31 @@ def test_config_file_states_its_size(cfg):
     with open(os.path.join(spec.ROOT, cfg["file"])) as f:
         body = json.load(f)
     assert body["name"] == cfg["name"] and body["reduced"] == cfg["reduced"]
-    assert flops.param_count(body["model"]) == body["param_count"]
+    assert spec.family(body["family"]).param_count(body["model"]) == \
+        body["param_count"]
+
+
+# what every family gives (``families/__init__.py``)
+CONTRACT = ("dataset", "local_steps", "batches", "init_params", "loss",
+            "param_count", "forward_flops_per_sample", "task",
+            "samples_per_iteration")
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_family_gives_the_contract(cfg):
+    with open(os.path.join(spec.ROOT, cfg["file"])) as f:
+        fam = spec.family(json.load(f)["family"])
+    assert all(callable(getattr(fam, name, None)) for name in CONTRACT)
+
+
+@pytest.mark.parametrize("name", [None, "no_such_family"])
+def test_unknown_or_missing_family_is_refused(name):
+    with pytest.raises(KeyError, match="paper_cnn"):
+        spec.family(name)
+    cell = spec.Cell("t", 1, {} if name is None else {"family": name},
+                     {}, {}, [], [])
+    with pytest.raises(KeyError, match="paper_cnn"):
+        _ = cell.family
 
 
 def test_names_units_and_bounds_keep_to_the_contract():

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from chipbench import flops
-from chipbench.spec import Cell, benchmark, metric_reader
+from chipbench.spec import Cell, benchmark, family, metric_reader
 from chipbench.trace import (WAGG_KERNEL, Events, Trace, TraceContext,
                              _assign_modules, gaps, union_ns)
 
@@ -16,7 +16,7 @@ KERNEL_OP = ("%closed_call.10 = f32[1,13312,128] custom-call(f32[3] %a, "
 
 
 def _cell(config_model, per_layer):
-    config = {"model": config_model,
+    config = {"family": "paper_cnn", "model": config_model,
               "task": {"num_clients": 100, "batch_size": 5,
                        "local_batches_per_step": 8}}
     return Cell("t", 1, config, {}, {}, per_layer, [])
@@ -81,17 +81,21 @@ def test_kernel_time_and_scan_time_per_upload():
 
 def test_roofline_counts_the_mac_call_bytes():
     ctx = _ctx({"uploads": 4})
-    n = flops.param_count(MODEL)
+    n = family("paper_cnn").param_count(MODEL)
     least = flops.wagg_bytes(n, 100, 1) / PEAKS["hbm_bytes_per_s"]
     got = metric_reader("wagg_roofline")(ctx)
     assert got == pytest.approx(100 * least / 1e-3)
+    # pinned exactly: a change of the counts does not pass unseen
+    assert got == 82.86385347985347
 
 
 def test_mfu_and_launches_from_counters():
     ctx = _ctx({"uploads": 4, "samples": 1000, "launches": 2})
-    want = 100 * 3 * flops.forward_flops_per_sample(MODEL) * 1000 / (
-        0.020 * PEAKS["flops_per_s"])
+    fwd = family("paper_cnn").forward_flops_per_sample(MODEL)
+    want = 100 * 3 * fwd * 1000 / (0.020 * PEAKS["flops_per_s"])
     assert metric_reader("mfu")(ctx) == pytest.approx(want)
+    # pinned exactly: a change of the counts does not pass unseen
+    assert metric_reader("mfu")(ctx) == 1.869007918781726
     assert metric_reader("launches_per_kupload")(ctx) == 500.0
 
 
