@@ -22,6 +22,7 @@ _ARCH_MODULES = (
     "qwen2_0_5b",
     "mixtral_8x7b",
     "zamba2_7b",
+    "granite_4_0_h_micro",
     "paper_cnn",
 )
 

@@ -80,6 +80,8 @@ class AttentionConfig:
     qkv_bias: bool = False
     attn_logit_softcap: float = 0.0   # 0 = disabled (gemma2: 50.0)
     query_pre_attn_scalar: float = 0.0  # 0 -> default 1/sqrt(head_dim)
+    # "rope" or "nope" (no position embedding: granite-4.0-h)
+    position_embedding: str = "rope"
 
 
 @dataclass(frozen=True)
@@ -107,8 +109,23 @@ class ModelConfig:
     final_logit_softcap: float = 0.0
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # the token embedding is multiplied by this (gemma2: sqrt(d_model);
+    # granite: 12)
+    embedding_multiplier: float = 1.0
+    # each sublayer's output is multiplied by this before it is added to
+    # the residual stream (granite: 0.22)
+    residual_multiplier: float = 1.0
+    # the logits are divided by this (granite: 8)
+    logits_scaling: float = 1.0
     # gated (SwiGLU) vs plain 2-matrix MLP (starcoder2 uses plain GELU MLP)
     mlp_gated: bool = True
+    # the gate's activation: "gelu" or "silu" (granite)
+    mlp_act: str = "gelu"
+    # the gated MLP's gate and up projections as one (d, 2·d_ff) matrix,
+    # gate first (granite's ``input_linear``)
+    mlp_packed: bool = False
+    # a gated-MLP sublayer after every Mamba mixer (granite-4.0-h)
+    mamba_mlp: bool = False
     # gemma2-style post-attention/post-ffn norms
     use_post_norms: bool = False
     # activation dtype for compute
@@ -283,6 +300,8 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         if kind == MAMBA:
             p = cfg.d_model                        # single pre-norm
             p += _mamba_params(cfg)
+            if cfg.mamba_mlp:
+                p += cfg.d_model + _mlp_params(cfg, cfg.d_ff)
         else:
             p = 2 * cfg.d_model                    # pre-attn + pre-ffn norms
             if cfg.use_post_norms:

@@ -3,6 +3,8 @@ attn logit softcap 50, final logit softcap 30, head_dim=256, GeGLU.
 
 [arXiv:2408.00118] Gemma 2.
 """
+import math
+
 from repro.configs.base import (
     ATTN_GLOBAL, ATTN_LOCAL, AttentionConfig, DENSE, ModelConfig, register,
 )
@@ -27,5 +29,6 @@ CONFIG = register(ModelConfig(
     final_logit_softcap=30.0,
     use_post_norms=True,
     tie_embeddings=True,
+    embedding_multiplier=math.sqrt(3584),    # gemma scales by sqrt(d_model)
     source="arXiv:2408.00118",
 ))

@@ -2,6 +2,8 @@
 
 [hf:ibm-granite/granite-3.0-1b-a400m-base]
 """
+import math
+
 from repro.configs.base import AttentionConfig, MOE, MoEConfig, ModelConfig, register
 
 CONFIG = register(ModelConfig(
@@ -17,5 +19,9 @@ CONFIG = register(ModelConfig(
     moe=MoEConfig(num_experts=32, top_k=8, expert_d_ff=512,
                   capacity_factor=1.25, group_size=4096),
     tie_embeddings=True,
+    # sqrt(d_model), as the tied embedding was scaled before the scale
+    # became a stated value; the published multiplier is not in the
+    # sources cited here
+    embedding_multiplier=math.sqrt(1024),
     source="hf:ibm-granite/granite-3.0-1b-a400m-base",
 ))

@@ -18,5 +18,6 @@ CONFIG = register(ModelConfig(
     ssm=SSMConfig(d_state=128, d_conv=4, expand=2, head_dim=64,
                   n_groups=1, chunk_size=128),
     tie_embeddings=True,
+    embedding_multiplier=1.0,      # Mamba-2 does not scale its embedding
     source="arXiv:2405.21060",
 ))

@@ -15,5 +15,6 @@ CONFIG = register(ModelConfig(
     vocab_size=151936,
     attention=AttentionConfig(rope_theta=1_000_000.0, qkv_bias=True),
     tie_embeddings=True,
+    embedding_multiplier=1.0,      # Qwen2 does not scale its embedding
     source="arXiv:2407.10671",
 ))

@@ -21,5 +21,6 @@ CONFIG = register(ModelConfig(
     ),
     mlp_gated=False,
     tie_embeddings=True,
+    embedding_multiplier=1.0,      # StarCoder2 does not scale its embedding
     source="arXiv:2402.19173",
 ))

@@ -3,9 +3,12 @@
 ``CNNTask`` is the paper's §IV setup (CNN on (Fashion-)MNIST-like data);
 ``LMTask`` federates a (reduced) assigned transformer architecture over
 synthetic non-IID token streams — the modern deployment of the algorithm
-used by the examples and integration tests.
+used by the examples and integration tests; ``LoRALMTask`` federates
+LoRA adapters over a frozen language model, each client's row being its
+adapters.
 
-Both tasks expose two local-training surfaces:
+``CNNTask`` and ``LMTask`` expose two local-training surfaces
+(``LoRALMTask`` only the second):
 
 * ``local_train_fn`` — the per-minibatch reference path (one jitted SGD
   dispatch per minibatch).  The CNN variant stages the WHOLE training
@@ -19,6 +22,7 @@ Both tasks expose two local-training surfaces:
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional
 
 import jax
@@ -30,8 +34,9 @@ from repro.configs.base import ModelConfig
 from repro.configs.paper_cnn import CNNConfig, MNIST_CNN
 from repro.data import federated as fd
 from repro.data.mnist_like import make_dataset
-from repro.data.synthetic import TokenStream
+from repro.data.synthetic import TokenStream, topic_corpus
 from repro.models import cnn as cnn_mod
+from repro.models import lora
 from repro.models import transformer as tmod
 
 
@@ -320,3 +325,98 @@ class LMTask:
 
     def eval_fn(self, params) -> Dict[str, float]:
         return {"loss": float(self._eval(params))}
+
+
+# ---------------------------------------------------------------------------
+# LoRA LM task (adapters over a frozen base)
+# ---------------------------------------------------------------------------
+class LoRALMTask:
+    """Federated LoRA fine-tuning of a language model (``models/lora.py``):
+    the engine's tree, and so each client's row, is the adapters alone.
+
+    The frozen base (``lora.frozen_base``) and the token corpus
+    (``data.synthetic.topic_corpus``: ``train_seqs`` training sequences,
+    then ``eval_seqs`` held out) are drawn from ``seed`` and live on the
+    device once; both are arguments of every plane program and of the
+    eval (a ``Partial``, as ``CNNTask`` binds its images), never
+    constants.  The training sequences are partitioned iid or by topic;
+    a client's minibatches are index rows into the corpus drawn from
+    ``(cid, seed)`` alone (``ClientDataset.batch_indices``), so a run's
+    batches do not depend on what ran before it.  Local training is
+    plain SGD on the adapters; the loss is the mean next-token cross
+    entropy, the vocabulary projection chunked over the sequence."""
+
+    def __init__(self, cfg: ModelConfig, *, rank: int = 16,
+                 alpha: float = 32.0, iid: bool = True,
+                 num_clients: int = 8, batch_size: int = 2,
+                 seq_len: int = 1024, local_batches_per_step: int = 1,
+                 train_seqs: int = 128, eval_seqs: int = 4,
+                 num_topics: int = 16, lr: float = 2.0, seed: int = 0):
+        self.cfg = cfg = dataclasses.replace(cfg, scan_layers=False)
+        self.rank, self.scale = rank, alpha / rank
+        self.lr = lr
+        self.batch_size = batch_size
+        self.local_batches = local_batches_per_step
+        self.base = lora.frozen_base(cfg, seed)
+        tokens, topics = topic_corpus(cfg.vocab_size, train_seqs + eval_seqs,
+                                      seq_len, num_topics=num_topics,
+                                      seed=seed)
+        self.tokens = jnp.asarray(tokens)
+        parts = fd.partition("iid" if iid else "label", topics[:train_seqs],
+                             num_clients, seed=seed,
+                             **({} if iid else {"classes_per_client": 2}))
+        self.clients = fd.make_clients(tokens[:train_seqs],
+                                       topics[:train_seqs], parts)
+        self._eval_rows = jnp.arange(train_seqs, train_seqs + eval_seqs)
+        scale = self.scale
+
+        # closes over no array: a cache that keeps a program's function
+        # keeps no base with it
+        def loss(base, tokens, adapters, rows):
+            seqs = tokens[rows]
+            batch = {"tokens": seqs[:, :-1], "labels": seqs[:, 1:]}
+            value, _ = tmod.loss_fn(lora.attach(base, adapters, scale), cfg,
+                                    batch, chunked=True)
+            return value
+
+        self._loss = loss
+        self._eval = jax.jit(loss)
+
+    def init_params(self, seed: int = 0):
+        return lora.init_adapters(self.cfg, self.rank,
+                                  jax.random.PRNGKey(seed), self.base)
+
+    def num_samples(self) -> List[int]:
+        return [c.num_samples for c in self.clients]
+
+    def _batch_rows(self, cid: int, num_steps: int, seed: int
+                    ) -> np.ndarray:
+        """(num_steps · local batches, batch_size) corpus rows."""
+        client = self.clients[cid]
+        local = client.batch_indices(self.batch_size,
+                                     num_steps * self.local_batches, seed)
+        return client.indices[local].astype(np.int32)
+
+    def client_plane(self, fleet, *, sharded: bool = False, **plane_kw):
+        """Fused fleet plane over the adapters; the base and the corpus
+        ride in the step's ``Partial``."""
+        from repro.core.agg_engine import engine_for
+        from repro.core.client_plane import build_plane
+
+        engine = engine_for(jax.eval_shape(self.init_params))
+        cast, loss, lr = engine.cast_expr, self._loss, self.lr
+
+        def step_fn(base, tokens, tree, rows):
+            """One SGD step on the adapters (the engine's tree)."""
+            grads = jax.grad(
+                lambda t: loss(base, tokens, cast(t), rows))(tree)
+            return jax.tree.map(lambda p, g: p - lr * g, tree, grads)
+
+        return build_plane(engine, fleet,
+                           Partial(step_fn, self.base, self.tokens),
+                           self._batch_rows, sharded=sharded, **plane_kw)
+
+    def eval_fn(self, params) -> Dict[str, float]:
+        """Mean loss on the held-out sequences."""
+        return {"loss": float(self._eval(self.base, self.tokens, params,
+                                         self._eval_rows))}

@@ -43,3 +43,21 @@ class TokenStream:
             stay = rng.random(batch) < 0.5
             toks[:, s] = np.where(stay, local % self.vocab, fresh)
         return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+
+
+def topic_corpus(vocab_size: int, num_seqs: int, seq_len: int, *,
+                 num_topics: int, seed: int):
+    """(tokens (num_seqs, seq_len + 1) int32, topics (num_seqs,) int32):
+    sequence i keeps to the vocabulary block of its topic — each token
+    lies in that block with probability 3/4, uniform inside it, and is
+    otherwise uniform over the vocabulary.  One numpy stream from
+    ``seed``: the topics, then the in-block draws, the block offsets and
+    the free draws, each as one array."""
+    rng = np.random.default_rng(seed)
+    block = vocab_size // num_topics
+    shape = (num_seqs, seq_len + 1)
+    topics = rng.integers(0, num_topics, num_seqs).astype(np.int32)
+    inside = rng.random(shape) < 0.75
+    local = topics[:, None] * block + rng.integers(0, block, shape)
+    free = rng.integers(0, vocab_size, shape)
+    return np.where(inside, local, free).astype(np.int32), topics

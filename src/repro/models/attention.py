@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_init, softcap
+from repro.models.layers import apply_rope, dense, dense_init, softcap
 
 Params = Dict[str, Any]
 NEG_INF = -2.0e38
@@ -54,14 +54,17 @@ def attention_init(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Params:
 def _project_qkv(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                  positions: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, d) -> q (B,S,H,D), k/v (B,S,Hkv,D), RoPE applied."""
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+    """x: (B, S, d) -> q (B,S,H,D), k/v (B,S,Hkv,D), RoPE applied
+    unless the model has no position embedding ("nope")."""
+    q = dense(params["wq"], x, "bsd,dhk->bshk")
+    k = dense(params["wk"], x, "bsd,dhk->bshk")
+    v = dense(params["wv"], x, "bsd,dhk->bshk")
     if "bq" in params:
         q = q + params["bq"]
         k = k + params["bk"]
         v = v + params["bv"]
+    if cfg.attention.position_embedding == "nope":
+        return q, k, v
     theta = cfg.attention.rope_theta
     q = apply_rope(q, positions, theta)
     k = apply_rope(k, positions, theta)
@@ -260,7 +263,7 @@ def attention_forward(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             logit_cap=cap, interpret=pallas_interpret())
     else:
         raise ValueError(f"unknown attention impl {impl}")
-    out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
+    out = dense(params["wo"], ctx, "bshk,hkd->bsd")
     if kv_cache_out:
         return out, (k, v)
     return out
@@ -334,7 +337,7 @@ def decode_attention(params: Params, x: jnp.ndarray, cache: Params,
     logits = jnp.where(valid[None, None, None, :], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     ctx = jnp.einsum("bhqk,bkhd->bqhd", w, vr.astype(jnp.float32))
-    out = jnp.einsum("bshk,hkd->bsd", ctx.astype(x.dtype), params["wo"])
+    out = dense(params["wo"], ctx.astype(x.dtype), "bshk,hkd->bsd")
     return out, new_cache
 
 

@@ -12,6 +12,7 @@ so decode scans carry the cache through the same period body.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 from repro.configs.base import ATTN_LOCAL, MAMBA, ModelConfig
 from repro.models import attention as attn
 from repro.models import mamba2, moe
-from repro.models.layers import mlp, mlp_init, rmsnorm, rmsnorm_init
+from repro.models.layers import dense, mlp, mlp_init, rmsnorm, rmsnorm_init
 
 Params = Dict[str, Any]
 
@@ -32,8 +33,12 @@ def block_init(key, kind: str, cfg: ModelConfig, *, dtype=jnp.float32
                ) -> Params:
     if kind == MAMBA:
         k1, k2 = jax.random.split(key)
-        return {"norm": rmsnorm_init(cfg.d_model),
-                "mixer": mamba2.mamba2_init(k1, cfg, dtype=dtype)}
+        p = {"norm": rmsnorm_init(cfg.d_model),
+             "mixer": mamba2.mamba2_init(k1, cfg, dtype=dtype)}
+        if cfg.mamba_mlp:
+            p["norm2"] = rmsnorm_init(cfg.d_model)
+            p["mlp"] = _mlp_init(k2, cfg, dtype)
+        return p
     k1, k2 = jax.random.split(key)
     p = {"norm1": rmsnorm_init(cfg.d_model),
          "attn": attn.attention_init(k1, cfg, dtype=dtype),
@@ -44,9 +49,24 @@ def block_init(key, kind: str, cfg: ModelConfig, *, dtype=jnp.float32
     if cfg.moe is not None:
         p["moe"] = moe.moe_init(k2, cfg, dtype=dtype)
     else:
-        p["mlp"] = mlp_init(k2, cfg.d_model, cfg.d_ff, gated=cfg.mlp_gated,
-                            dtype=dtype)
+        p["mlp"] = _mlp_init(k2, cfg, dtype)
     return p
+
+
+def _mlp_init(key, cfg: ModelConfig, dtype) -> Params:
+    return mlp_init(key, cfg.d_model, cfg.d_ff, gated=cfg.mlp_gated,
+                    packed=cfg.mlp_packed, dtype=dtype)
+
+
+def _mlp(params: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    return mlp(params, h, gated=cfg.mlp_gated, act=cfg.mlp_act)
+
+
+def _add(x: jnp.ndarray, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The residual stream plus a sublayer's output, times the model's
+    residual multiplier."""
+    r = cfg.residual_multiplier
+    return x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
 
 
 def _block_window(kind: str, cfg: ModelConfig) -> int:
@@ -74,22 +94,23 @@ def block_forward(params: Params, x: jnp.ndarray, kind: str,
     aux = jnp.zeros((), jnp.float32)
     if kind == MAMBA:
         h = _constrain_block_input(rmsnorm(params["norm"], x, cfg.norm_eps))
-        return x + mamba2.mamba2_forward(params["mixer"], h, cfg), aux
+        x = _add(x, mamba2.mamba2_forward(params["mixer"], h, cfg), cfg)
+        return _mamba_mlp(params, x, cfg), aux
     window = _block_window(kind, cfg)
     h = _constrain_block_input(rmsnorm(params["norm1"], x, cfg.norm_eps))
     h = attn.attention_forward(params["attn"], h, cfg, window=window,
                                positions=positions, impl=attn_impl)
     if cfg.use_post_norms:
         h = rmsnorm(params["post_norm1"], h, cfg.norm_eps)
-    x = x + h
+    x = _add(x, h, cfg)
     h = _constrain_block_input(rmsnorm(params["norm2"], x, cfg.norm_eps))
     if cfg.moe is not None:
         h, aux = moe.moe_forward(params["moe"], h, cfg)
     else:
-        h = mlp(params["mlp"], h, gated=cfg.mlp_gated)
+        h = _mlp(params["mlp"], h, cfg)
     if cfg.use_post_norms:
         h = rmsnorm(params["post_norm2"], h, cfg.norm_eps)
-    return x + h, aux
+    return _add(x, h, cfg), aux
 
 
 # ---------------------------------------------------------------------------
@@ -109,22 +130,31 @@ def block_decode(params: Params, x: jnp.ndarray, cache: Params, kind: str,
     if kind == MAMBA:
         h = rmsnorm(params["norm"], x, cfg.norm_eps)
         y, new_cache = mamba2.mamba2_decode(params["mixer"], h, cache, cfg)
-        return x + y, new_cache
+        return _mamba_mlp(params, _add(x, y, cfg), cfg), new_cache
     window = _block_window(kind, cfg)
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
     h, new_cache = attn.decode_attention(params["attn"], h, cache, cfg,
                                          pos=pos, window=window)
     if cfg.use_post_norms:
         h = rmsnorm(params["post_norm1"], h, cfg.norm_eps)
-    x = x + h
+    x = _add(x, h, cfg)
     h = rmsnorm(params["norm2"], x, cfg.norm_eps)
     if cfg.moe is not None:
         h = moe.moe_decode(params["moe"], h, cfg)
     else:
-        h = mlp(params["mlp"], h, gated=cfg.mlp_gated)
+        h = _mlp(params["mlp"], h, cfg)
     if cfg.use_post_norms:
         h = rmsnorm(params["post_norm2"], h, cfg.norm_eps)
-    return x + h, new_cache
+    return _add(x, h, cfg), new_cache
+
+
+def _mamba_mlp(params: Params, x: jnp.ndarray, cfg: ModelConfig
+               ) -> jnp.ndarray:
+    """The MLP sublayer after a Mamba mixer, where the model has one."""
+    if not cfg.mamba_mlp:
+        return x
+    h = _constrain_block_input(rmsnorm(params["norm2"], x, cfg.norm_eps))
+    return _add(x, _mlp(params["mlp"], h, cfg), cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -240,8 +270,12 @@ def stack_forward(params: Params, x: jnp.ndarray, cfg: ModelConfig,
         rem_kinds = cfg.blocks
     for i, (p, kind) in enumerate(zip(params["rem"], rem_kinds)):
         p = _gather_layer(p, i, "rem")
-        x, a = block_forward(p, x, kind, cfg, positions=positions,
-                             attn_impl=attn_impl)
+        fwd = functools.partial(block_forward, kind=kind, cfg=cfg,
+                                positions=positions, attn_impl=attn_impl)
+        if cfg.remat:
+            # per-block remat: backward keeps only the block's input
+            fwd = jax.checkpoint(fwd)
+        x, a = fwd(p, x)
         x = _constrain(x)     # same carry layout as the scanned path
         aux = aux + a
     return x, aux
@@ -310,7 +344,7 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             # full forward; final state via ssd_chunked on the side
             y = mamba2.mamba2_forward(p["mixer"], hn, cfg)
             new_c = _mamba_prefill_state(p["mixer"], hn, cfg, c)
-            return h + y, new_c
+            return _mamba_mlp(p, _add(h, y, cfg), cfg), new_c
         window = _block_window(kind, cfg)
         hn = rmsnorm(p["norm1"], h, cfg.norm_eps)
         out, (k_new, v_new) = attn.attention_forward(
@@ -318,16 +352,16 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             impl=attn_impl, kv_cache_out=True)
         if cfg.use_post_norms:
             out = rmsnorm(p["post_norm1"], out, cfg.norm_eps)
-        h = h + out
+        h = _add(h, out, cfg)
         hn = rmsnorm(p["norm2"], h, cfg.norm_eps)
         if cfg.moe is not None:
             hn, _ = moe.moe_forward(p["moe"], hn, cfg)
         else:
-            hn = mlp(p["mlp"], hn, gated=cfg.mlp_gated)
+            hn = _mlp(p["mlp"], hn, cfg)
         if cfg.use_post_norms:
             hn = rmsnorm(p["post_norm2"], hn, cfg.norm_eps)
         new_c = attn.fill_kv_cache(c, k_new, v_new)
-        return h + hn, new_c
+        return _add(h, hn, cfg), new_c
 
     from repro.sharding.context import get_activation_spec
     act_spec = get_activation_spec()
@@ -367,7 +401,7 @@ def _mamba_prefill_state(mixer: Params, h: jnp.ndarray, cfg: ModelConfig,
     s = cfg.ssm
     d_in = cfg.d_inner
     nh = cfg.ssm_heads
-    zxbcdt = h @ mixer["in_proj"]
+    zxbcdt = dense(mixer["in_proj"], h)
     _, xi, Bm, Cm, dt = mamba2._split_in_proj(cfg, zxbcdt)
     xBC = jnp.concatenate([xi, Bm, Cm], axis=-1)
     conv_state = xBC[:, -(s.d_conv - 1):, :]

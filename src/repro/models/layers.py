@@ -12,7 +12,18 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.models.lora import Adapted
+
 Params = Dict[str, Any]
+
+
+def dense(w, x: jnp.ndarray, eq: Optional[str] = None) -> jnp.ndarray:
+    """``x @ w``, or ``einsum(eq, x, w)`` where ``w`` has more than two
+    axes; a LoRA-adapted weight (``lora.Adapted``) adds its low-rank
+    term, unmerged."""
+    if isinstance(w, Adapted):
+        return w.add_low_rank(x, dense(w.w, x, eq))
+    return jnp.einsum(eq, x, w) if eq else x @ w
 
 
 # ---------------------------------------------------------------------------
@@ -79,9 +90,17 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # MLP (gated SwiGLU / GeGLU, or plain GELU)
 # ---------------------------------------------------------------------------
+_ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
+
+
 def mlp_init(key, d_model: int, d_ff: int, *, gated: bool = True,
-             dtype=jnp.float32) -> Params:
+             packed: bool = False, dtype=jnp.float32) -> Params:
+    """``packed``: the gate and up projections as one (d, 2·d_ff)
+    ``w_in``, gate first."""
     ks = jax.random.split(key, 3)
+    if packed:
+        return {"w_in": dense_init(ks[0], d_model, 2 * d_ff, dtype=dtype),
+                "w_out": dense_init(ks[2], d_ff, d_model, dtype=dtype)}
     p = {"w_in": dense_init(ks[0], d_model, d_ff, dtype=dtype),
          "w_out": dense_init(ks[2], d_ff, d_model, dtype=dtype)}
     if gated:
@@ -89,31 +108,47 @@ def mlp_init(key, d_model: int, d_ff: int, *, gated: bool = True,
     return p
 
 
-def mlp(params: Params, x: jnp.ndarray, *, gated: bool = True) -> jnp.ndarray:
-    h = x @ params["w_in"]
-    if gated:
-        h = jax.nn.gelu(x @ params["w_gate"]) * h
+def mlp(params: Params, x: jnp.ndarray, *, gated: bool = True,
+        act: str = "gelu") -> jnp.ndarray:
+    """A packed ``w_in`` (no ``w_gate``) of a gated MLP holds the gate's
+    columns first, then the up projection's."""
+    f = _ACTIVATIONS[act]
+    h = dense(params["w_in"], x)
+    if gated and "w_gate" not in params:
+        gate, h = jnp.split(h, 2, axis=-1)
+        h = f(gate) * h
+    elif gated:
+        h = f(dense(params["w_gate"], x)) * h
     else:
-        h = jax.nn.gelu(h)
-    return h @ params["w_out"]
+        h = f(h)
+    return dense(params["w_out"], h)
 
 
 # ---------------------------------------------------------------------------
 # Embedding / unembedding
 # ---------------------------------------------------------------------------
 def embed_lookup(embedding: jnp.ndarray, ids: jnp.ndarray,
-                 *, scale_by_sqrt_dim: bool = False) -> jnp.ndarray:
+                 *, multiplier: float = 1.0) -> jnp.ndarray:
     x = jnp.take(embedding, ids, axis=0)
-    if scale_by_sqrt_dim:
-        x = x * jnp.asarray(math.sqrt(embedding.shape[1]), x.dtype)
+    if multiplier != 1.0:
+        x = x * jnp.asarray(multiplier, x.dtype)
     return x
 
 
+def scale_logits(logits: jnp.ndarray, final_softcap: float = 0.0,
+                 logits_scaling: float = 1.0) -> jnp.ndarray:
+    """Logits divided by ``logits_scaling``, then soft-capped."""
+    if logits_scaling != 1.0:
+        logits = logits / jnp.asarray(logits_scaling, logits.dtype)
+    return softcap(logits, final_softcap)
+
+
 def unembed(x: jnp.ndarray, table: jnp.ndarray,
-            final_softcap: float = 0.0) -> jnp.ndarray:
+            final_softcap: float = 0.0,
+            logits_scaling: float = 1.0) -> jnp.ndarray:
     """table is always (vocab, d_model)."""
     logits = jnp.einsum("...d,vd->...v", x, table)
-    return softcap(logits, final_softcap)
+    return scale_logits(logits, final_softcap, logits_scaling)
 
 
 # ---------------------------------------------------------------------------

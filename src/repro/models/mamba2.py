@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import dense_init
+from repro.models.layers import dense, dense_init
 
 Params = Dict[str, Any]
 
@@ -210,7 +210,7 @@ def mamba2_forward(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     B_, L, _ = x.shape
     d_in = cfg.d_inner
     nh = cfg.ssm_heads
-    zxbcdt = x @ params["in_proj"]
+    zxbcdt = dense(params["in_proj"], x)
     z, xi, Bm, Cm, dt = _split_in_proj(cfg, zxbcdt)
     xBC = jnp.concatenate([xi, Bm, Cm], axis=-1)
     xBC = jax.nn.silu(causal_conv1d(xBC, params["conv_w"], params["conv_b"]))
@@ -239,7 +239,7 @@ def mamba2_forward(params: Params, x: jnp.ndarray, cfg: ModelConfig,
         * params["D"][None, None, :, None]
     y = y.reshape(B_, L, d_in).astype(x.dtype)
     y = _gated_rmsnorm(params["norm_scale"], y, z, cfg.norm_eps)
-    return y @ params["out_proj"]
+    return dense(params["out_proj"], y)
 
 
 def init_mamba_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16
@@ -260,7 +260,7 @@ def mamba2_decode(params: Params, x: jnp.ndarray, cache: Params,
     B_ = x.shape[0]
     d_in = cfg.d_inner
     nh = cfg.ssm_heads
-    zxbcdt = x[:, 0] @ params["in_proj"]
+    zxbcdt = dense(params["in_proj"], x[:, 0])
     z, xi, Bm, Cm, dt = _split_in_proj(cfg, zxbcdt)
     xBC = jnp.concatenate([xi, Bm, Cm], axis=-1)
     y_conv, new_conv = conv1d_decode_step(
@@ -277,5 +277,5 @@ def mamba2_decode(params: Params, x: jnp.ndarray, cache: Params,
     y = y + xh.astype(jnp.float32) * params["D"][None, :, None]
     y = y.reshape(B_, d_in).astype(x.dtype)
     y = _gated_rmsnorm(params["norm_scale"], y, z, cfg.norm_eps)
-    out = (y @ params["out_proj"])[:, None, :]
+    out = dense(params["out_proj"], y)[:, None, :]
     return out, {"conv": new_conv.astype(cache["conv"].dtype), "ssm": new_ssm}

@@ -23,7 +23,8 @@ from repro.configs.base import ENCDEC, ModelConfig, VLM
 from repro.models import attention as attn_mod
 from repro.models import blocks as blk
 from repro.models.layers import (cross_entropy, dense_init, embed_init,
-                                 embed_lookup, rmsnorm, rmsnorm_init, unembed)
+                                 embed_lookup, rmsnorm, rmsnorm_init,
+                                 scale_logits, unembed)
 
 Params = Dict[str, Any]
 
@@ -101,7 +102,7 @@ def _embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
                   ) -> jnp.ndarray:
     tokens = batch["tokens"]
     x = embed_lookup(params["embed"], tokens,
-                     scale_by_sqrt_dim=cfg.tie_embeddings)
+                     multiplier=cfg.embedding_multiplier)
     if cfg.family == VLM and "patch_embeds" in batch:
         pe = batch["patch_embeds"]  # (B, P, vision_embed_dim)
         proj = pe.astype(x.dtype) @ params["vis_proj"]["w"] \
@@ -124,7 +125,8 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
     x = _embed_inputs(params, cfg, batch)
     x, aux = blk.stack_forward(params["stack"], x, cfg, attn_impl=attn_impl)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(x, _lm_head_table(params, cfg), cfg.final_logit_softcap)
+    logits = unembed(x, _lm_head_table(params, cfg), cfg.final_logit_softcap,
+                     cfg.logits_scaling)
     return logits, aux
 
 
@@ -166,11 +168,12 @@ def _forward_encdec(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
     enc_out = _encode(params, cfg, batch["frame_embeds"].astype(
         params["embed"].dtype), attn_impl)
     x = embed_lookup(params["embed"], batch["tokens"],
-                     scale_by_sqrt_dim=cfg.tie_embeddings)
+                     multiplier=cfg.embedding_multiplier)
     x, aux = blk.stack_forward(params["stack"], x, cfg, attn_impl=attn_impl)
     x = _apply_cross(params["cross"], x, enc_out, cfg)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(x, _lm_head_table(params, cfg), cfg.final_logit_softcap)
+    logits = unembed(x, _lm_head_table(params, cfg), cfg.final_logit_softcap,
+                     cfg.logits_scaling)
     return logits, aux
 
 
@@ -185,7 +188,7 @@ def hidden_forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
         enc_out = _encode(params, cfg, batch["frame_embeds"].astype(
             params["embed"].dtype), attn_impl)
         x = embed_lookup(params["embed"], batch["tokens"],
-                         scale_by_sqrt_dim=cfg.tie_embeddings)
+                         multiplier=cfg.embedding_multiplier)
         x, aux = blk.stack_forward(params["stack"], x, cfg,
                                    attn_impl=attn_impl)
         x = _apply_cross(params["cross"], x, enc_out, cfg)
@@ -198,7 +201,7 @@ def hidden_forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
 
 def chunked_cross_entropy(x: jnp.ndarray, table: jnp.ndarray,
                           labels: jnp.ndarray, *, final_softcap: float = 0.0,
-                          chunk: int = 128,
+                          logits_scaling: float = 1.0, chunk: int = 128,
                           row_weights: Optional[jnp.ndarray] = None
                           ) -> jnp.ndarray:
     """CE over vocab-sharded logits without materializing (B,S,V).
@@ -224,8 +227,7 @@ def chunked_cross_entropy(x: jnp.ndarray, table: jnp.ndarray,
     def body(carry, inp):
         xc, lc = inp                                   # (B,c,d), (B,c)
         logits = jnp.einsum("bcd,vd->bcv", xc, table).astype(jnp.float32)
-        from repro.models.layers import softcap as _sc
-        logits = _sc(logits, final_softcap)
+        logits = scale_logits(logits, final_softcap, logits_scaling)
         lse = jax.nn.logsumexp(logits, axis=-1)        # (B,c)
         oh = jax.nn.one_hot(lc, V, dtype=jnp.float32)  # (B,c,V)
         ll = jnp.sum(logits * oh, axis=-1)
@@ -261,6 +263,7 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
             x = x[:, P:, :]
         loss = chunked_cross_entropy(x, _lm_head_table(params, cfg), labels,
                                      final_softcap=cfg.final_logit_softcap,
+                                     logits_scaling=cfg.logits_scaling,
                                      row_weights=row_weights)
     else:
         logits, aux = forward(params, cfg, batch, attn_impl=attn_impl)
@@ -306,7 +309,8 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
                                          cache["dec"], attn_impl=attn_impl)
         new_cache = {"dec": dec_cache}
     h = rmsnorm(params["final_norm"], h[:, -1:, :], cfg.norm_eps)
-    logits = unembed(h, _lm_head_table(params, cfg), cfg.final_logit_softcap)
+    logits = unembed(h, _lm_head_table(params, cfg), cfg.final_logit_softcap,
+                     cfg.logits_scaling)
     return logits, new_cache
 
 
@@ -315,7 +319,7 @@ def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
     """token (B, 1) int32; pos scalar int32 (absolute position of `token`).
     Returns (logits (B,1,V), new_cache)."""
     x = embed_lookup(params["embed"], token,
-                     scale_by_sqrt_dim=cfg.tie_embeddings)
+                     multiplier=cfg.embedding_multiplier)
     h, new_dec = blk.stack_decode(params["stack"], x, cache["dec"], cfg,
                                   pos=pos)
     new_cache = {"dec": new_dec}
@@ -323,7 +327,8 @@ def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
         h = _apply_cross(params["cross"], h, cache["enc_out"], cfg)
         new_cache["enc_out"] = cache["enc_out"]
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = unembed(h, _lm_head_table(params, cfg), cfg.final_logit_softcap)
+    logits = unembed(h, _lm_head_table(params, cfg), cfg.final_logit_softcap,
+                     cfg.logits_scaling)
     return logits, new_cache
 
 
